@@ -30,16 +30,7 @@ The historical entry points (``frogwild_run``, ``distributed_frogwild``,
 ``build_walk_index{,_sharded}``, ``QueryScheduler.submit/run``) remain as
 deprecation shims that delegate through the service and return
 byte-identical results.
-
-Importing ``repro`` (any submodule) installs the jax version-compat shims —
-the codebase targets the jax ≥ 0.5 public API (``jax.shard_map``,
-``jax.sharding.AxisType``, ``make_mesh(axis_types=)``) and
-``distributed/compat.py`` back-fills those names on older containers.
 """
-from repro.distributed.compat import install as _install_jax_compat
-
-_install_jax_compat()
-
 from repro.config import (KernelConfig, RuntimeConfig, ServingConfig,
                           ShardConfig)
 from repro.gateway import Gateway

@@ -44,6 +44,8 @@ Everything is pure JAX (lax.scan over steps) and runs on CPU.
 from __future__ import annotations
 
 import dataclasses
+import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +55,8 @@ from repro.core.blocking import (channel_enum_draw, coin_uniform,
                                  rejection_blocking_draw,
                                  rejection_is_profitable)
 from repro.graph.csr import CSRGraph, uniform_successor
+from repro.kernels import ops
+from repro.kernels.frog_step_stream import BlockedCSR, block_csr
 
 # FrogWildConfig is defined in repro/config.py (the layered-config module —
 # single definition per flag) and re-exported here for back-compat.
@@ -143,6 +147,16 @@ def draw_next_rejection(
     raise ValueError(f"unknown erasure model {cfg.erasure!r}")
 
 
+def _draw_impl(cfg: FrogWildConfig, num_frogs: int, nnz: int) -> str:
+    """``cfg.draw`` with ``auto`` resolved for ``num_frogs`` walkers."""
+    if cfg.draw != "auto":
+        return cfg.draw
+    nc = cfg.num_shards if cfg.erasure == "channel" else None
+    return ("rejection"
+            if rejection_is_profitable(num_frogs, nnz, cfg.p_s, nc)
+            else "cumsum")
+
+
 def draw_next(
     g: CSRGraph, cfg: FrogWildConfig, key: jax.Array, pos: jnp.ndarray
 ) -> jnp.ndarray:
@@ -154,12 +168,7 @@ def draw_next(
     Module-level so tests and benchmarks can exercise a single superstep's
     draw in isolation.
     """
-    draw = cfg.draw
-    if draw == "auto":
-        nc = cfg.num_shards if cfg.erasure == "channel" else None
-        draw = ("rejection"
-                if rejection_is_profitable(pos.shape[0], g.nnz, cfg.p_s, nc)
-                else "cumsum")
+    draw = _draw_impl(cfg, pos.shape[0], g.nnz)
     if draw == "cumsum":
         return draw_next_cumsum(g, cfg, key, pos)
     elif draw == "rejection":
@@ -181,18 +190,24 @@ def frogwild_run(
     return service.batch_pagerank(g, cfg, key=key)
 
 
+def _uses_erasure(cfg: FrogWildConfig) -> bool:
+    return cfg.erasure != "none" and cfg.p_s < 1.0
+
+
 def _frogwild_walks(
     g: CSRGraph,
     cfg: FrogWildConfig,
     key: jax.Array,
+    blocked: Optional[BlockedCSR] = None,
 ) -> FrogWildResult:
-    """Runs the FrogWild! process and returns the stop-counter estimator."""
+    """Runs the FrogWild! process and returns the stop-counter estimator.
+    ``blocked`` is the streamed step's slab layout, for traced graphs."""
     n = g.n
     N, t = cfg.num_frogs, cfg.num_steps
     row_ptr = g.row_ptr
     col_idx = g.col_idx
     deg = g.out_deg
-    use_erasure = cfg.erasure != "none" and cfg.p_s < 1.0
+    use_erasure = _uses_erasure(cfg)
     use_fused = (not use_erasure) and cfg.step_impl != "xla"
 
     k_init, k_loop = jax.random.split(key)
@@ -210,12 +225,10 @@ def _frogwild_walks(
         # apply(): each arriving frog dies w.p. p_T and is tallied here.
         die = jax.random.bernoulli(k_die, cfg.p_T, shape=(N,)) & alive
         if use_fused:
-            from repro.kernels import ops
-
             slot_bits = jax.random.randint(k_move, (N,), 0, 1 << 30, jnp.int32)
             nxt, death_counts = ops.frog_step(
                 pos, die, slot_bits, row_ptr, col_idx, deg, n,
-                impl=cfg.step_impl,
+                impl=cfg.step_impl, blocked=blocked,
             )
             counts = counts + death_counts
         else:
@@ -235,14 +248,55 @@ def _frogwild_walks(
     return FrogWildResult(counts=counts, pi_hat=pi_hat, num_frogs=N)
 
 
-# jitted entry point (static graph arrays close over the trace)
+def _concrete_layouts(g: CSRGraph, cfg: FrogWildConfig):
+    """The layouts the walk under ``cfg`` reads that only the concrete
+    graph can build: the erasure draw's memoized per-edge arrays (keyed as
+    in ``g._derived``) and the streamed step's slab layout (or ``None``)."""
+    keys = []
+    if _uses_erasure(cfg):
+        S = cfg.num_shards
+        if _draw_impl(cfg, cfg.num_frogs, g.nnz) == "cumsum":
+            g.edge_dst_shard(S)
+            keys = [("edge_dst_shard", S)]
+            if cfg.erasure == "channel":
+                g.edge_src
+                keys.append("edge_src")
+        elif cfg.erasure == "channel":
+            g.channel_layout(S)
+            keys = [("channel_layout", S)]
+    blocked = None
+    if not _uses_erasure(cfg) and ops.step_streams(cfg.step_impl, g.n, g.nnz):
+        blocked = block_csr(g.row_ptr, g.col_idx, g.out_deg, g.n)
+    return {k: g._derived[k] for k in keys}, blocked
+
+
+@functools.partial(jax.jit, static_argnames=("n", "cfg", "layout_keys"))
+def _walks_program(row_ptr, col_idx, out_deg, layouts, blocked, key, *,
+                   n: int, cfg: FrogWildConfig, layout_keys: tuple):
+    g = CSRGraph(n=n, row_ptr=row_ptr, col_idx=col_idx, out_deg=out_deg,
+                 _derived=dict(zip(layout_keys, layouts)))
+    return _as_tuple(_frogwild_walks(g, cfg, key, blocked))
+
+
+def frogwild_walks(
+    g: CSRGraph, cfg: FrogWildConfig, key: jax.Array
+) -> FrogWildResult:
+    """One jitted FrogWild! run. The graph and its derived layouts are
+    operands: closed over, the CSR would be baked into the executable as
+    constants (about 700 MB of executable at LiveJournal's size, too large
+    for the compile cache, and recompiled for every call)."""
+    layouts, blocked = _concrete_layouts(g, cfg)
+    counts, pi_hat = _walks_program(
+        g.row_ptr, g.col_idx, g.out_deg, tuple(layouts.values()), blocked,
+        key, n=g.n, cfg=cfg, layout_keys=tuple(layouts))
+    return FrogWildResult(counts=counts, pi_hat=pi_hat,
+                          num_frogs=cfg.num_frogs)
+
+
 def frogwild(
     g: CSRGraph, cfg: FrogWildConfig, seed: int = 0
 ) -> FrogWildResult:
-    key = jax.random.PRNGKey(seed)
-    run = jax.jit(lambda k: _as_tuple(_frogwild_walks(g, cfg, k)))
-    counts, pi_hat = run(key)
-    return FrogWildResult(counts=counts, pi_hat=pi_hat, num_frogs=cfg.num_frogs)
+    return frogwild_walks(g, cfg, jax.random.PRNGKey(seed))
 
 
 def _as_tuple(r: FrogWildResult):

@@ -51,29 +51,27 @@ def power_iteration(
       num_iters: iterations. 50 ≈ machine-precision convergence at p_T=0.15
         (|λ2| ≤ 1 − p_T ⇒ error ≤ 0.85^50 ≈ 3e-4 of initial).
       p_T: teleport probability (paper uses 0.15 throughout).
-      spmv: "coo" (segment-sum, CPU-fast) or "ell" (Pallas kernel path).
+      spmv: "coo" (segment-sum) or "ell" (the hybrid-ELL Pallas kernel,
+        ``kernels.ops.spmv``: interpreted on CPU; on TPU it raises, since
+        Mosaic refuses the kernel's gather — see ``ops.TPU_REFUSED``).
     """
     if spmv == "coo":
         src, dst, w = transition_edges(g)
         return _power_iter_coo(src, dst, w, g.n, num_iters, p_T)
     elif spmv == "ell":
         from repro.graph.partition import to_ell
-        from repro.kernels import spmv_ops
+        from repro.kernels import ops
 
         ell = to_ell(g, K=32)
-        x = jnp.full((g.n,), 1.0 / n_round(g.n), dtype=jnp.float32)
+        x = jnp.full((g.n,), 1.0 / g.n, dtype=jnp.float32)
 
         def step(x, _):
-            px = spmv_ops.spmv(ell, x, interpret=True)[: g.n]
+            px = ops.spmv(ell, x)[: g.n]
             return (1.0 - p_T) * px + p_T / g.n, None
 
         x, _ = jax.lax.scan(step, x, None, length=num_iters)
         return x
     raise ValueError(f"unknown spmv impl {spmv!r}")
-
-
-def n_round(n: int, m: int = 8) -> int:
-    return ((n + m - 1) // m) * m
 
 
 def reduced_iteration_baseline(

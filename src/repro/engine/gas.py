@@ -506,6 +506,10 @@ def _distributed_frogwild(
     fn = jax.jit(_sharded_fn(dg, cfg, mesh))
     key_data = ShardRuntime.key_data(jax.random.PRNGKey(seed))
     counts, stats = fn(*dg.arrays(), key_data)
+    # Replicate before dropping the padding rows: on a mesh with Explicit
+    # axes (jax.make_mesh's default) slicing the sharded counts is refused
+    # as ambiguous.
+    counts = jax.device_put(counts, rt.sharding(replicated=True))
     counts = counts.reshape(-1)[: dg.n]
     stats = np.asarray(stats)                         # [S, t, 4]
     total = (cfg.num_frogs // dg.num_shards) * dg.num_shards

@@ -7,8 +7,11 @@
 * ``flash_attention`` — causal GQA flash attention (+ sliding window)
 
 Each has a jitted wrapper in ``ops.py`` and a pure-jnp oracle in ``ref.py``;
-tests sweep shapes/dtypes and assert allclose in interpret mode. Pallas is
-the TPU *target*: on this CPU container kernels execute via interpret=True.
+tests sweep shapes/dtypes and assert allclose in interpret mode. The
+wrappers resolve the mode from the backend (``ops.resolve_interpret``):
+interpreted on CPU, compiled on TPU, where the kernels in
+``ops.TPU_REFUSED`` do not compile. The raw kernels take the resolved
+mode as a required ``interpret=`` keyword.
 See README.md for the step-cost model and dispatch flags.
 """
 from repro.kernels import ops, ref

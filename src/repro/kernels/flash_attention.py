@@ -117,7 +117,8 @@ def flash_attention(
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
     soft_cap: Optional[float] = None,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     B, Hq, Sq, D = q.shape
     _, Hkv, Skv, _ = k.shape

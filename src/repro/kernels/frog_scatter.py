@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-DEFAULT_VERTEX_BLOCK = 512
+DEFAULT_VERTEX_BLOCK = 1024
 DEFAULT_FROG_BLOCK = 1024
 
 
@@ -48,7 +48,8 @@ def frog_count(
     n: int,                     # number of vertices (padded multiple of vertex_block)
     vertex_block: int = DEFAULT_VERTEX_BLOCK,
     frog_block: int = DEFAULT_FROG_BLOCK,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     (N,) = dest.shape
     if n % vertex_block != 0:

@@ -91,7 +91,8 @@ def frog_step(
     n_pad: int,              # counts bins, multiple of vertex_block
     vertex_block: int = DEFAULT_VERTEX_BLOCK,
     frog_block: int = DEFAULT_FROG_BLOCK,
-    interpret: bool = True,
+    *,
+    interpret: bool,
     use_device_rng: bool = False,
 ):
     """Returns ``(next_pos int32[N], death_counts int32[n_pad])``."""

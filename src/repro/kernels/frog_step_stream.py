@@ -60,6 +60,9 @@ DEFAULT_VERTEX_BLOCK = 512
 DEFAULT_FROG_BLOCK = 1024
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["row_off", "deg", "col"],
+                   meta_fields=["vertex_block"])
 @dataclasses.dataclass(frozen=True)
 class BlockedCSR:
     """CSR re-laid-out as uniform per-vertex-block slabs (the DMA unit).
@@ -223,7 +226,8 @@ def frog_step_stream_sorted(
     num_fb: int,
     vertex_block: int = DEFAULT_VERTEX_BLOCK,
     frog_block: int = DEFAULT_FROG_BLOCK,
-    interpret: bool = True,
+    *,
+    interpret: bool,
     use_device_rng: bool = False,
 ):
     """Streamed superstep over pre-sorted frogs.
@@ -243,9 +247,14 @@ def frog_step_stream_sorted(
             pl.BlockSpec((frog_block,), lambda b, vid: (b,)),       # pos
             pl.BlockSpec((frog_block,), lambda b, vid: (b,)),       # die
             bits_spec,                                              # bits | seed
-            pl.BlockSpec((1, vertex_block), lambda b, vid: (vid[b], 0)),
-            pl.BlockSpec((1, vertex_block), lambda b, vid: (vid[b], 0)),
-            pl.BlockSpec((1, e_blk), lambda b, vid: (vid[b], 0)),
+            # Slabs are viewed [num_vb, 1, ·]: Mosaic wants a block's last
+            # two dims divisible by (8, 128) or equal to the array's, and a
+            # (1, BV) block of a [num_vb, BV] array is neither.
+            pl.BlockSpec((None, 1, vertex_block),
+                         lambda b, vid: (vid[b], 0, 0)),
+            pl.BlockSpec((None, 1, vertex_block),
+                         lambda b, vid: (vid[b], 0, 0)),
+            pl.BlockSpec((None, 1, e_blk), lambda b, vid: (vid[b], 0, 0)),
         ],
         out_specs=(
             pl.BlockSpec((vertex_block,), lambda b, vid: (vid[b],)),
@@ -262,5 +271,6 @@ def frog_step_stream_sorted(
             jax.ShapeDtypeStruct((pos_p.shape[0],), jnp.int32),
         ),
         interpret=interpret,
-    )(blk_vid, pos_p, die_p, bits_p, row_off, deg, col)
+    )(blk_vid, pos_p, die_p, bits_p, row_off[:, None], deg[:, None],
+      col[:, None])
     return nxt, counts

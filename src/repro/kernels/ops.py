@@ -4,6 +4,12 @@ These handle padding, hybrid spill application, and backend dispatch
 (``impl="pallas"`` → interpret-mode kernel on CPU / compiled kernel on TPU,
 ``impl="ref"`` → pure-jnp oracle). Model code and the engine call these, so
 swapping implementations is a config flag, not a code change.
+
+The Pallas execution mode follows the backend (:func:`resolve_interpret`):
+every ``interpret`` argument defaults to ``None``, which means interpreted
+on the CPU backend and compiled on TPU. Kernels that the TPU compiler
+refuses (:data:`TPU_REFUSED`) raise with the compiler's reason when asked
+for explicitly in compiled mode, and no ``impl="auto"`` choice picks them.
 """
 from __future__ import annotations
 
@@ -27,6 +33,67 @@ from repro.kernels.stitch import stitch_gather_local as _stitch_gather_local
 from repro.kernels.stitch import stitch_step as _stitch_step
 from repro.kernels.stitch import stitch_step_local as _stitch_step_local
 
+# Kernels that Mosaic (the TPU compiler) refuses for v5e. Each gathers from
+# a VMEM table at per-walk dynamic indices (``jnp.take``), and Mosaic lowers
+# no 1-D dynamic gather. tests/test_tpu_compile.py re-checks every entry
+# against the compiler, so this table cannot drift from it.
+TPU_REFUSED = frozenset({
+    "frog_step", "frog_step_stream", "stitch_step", "stitch_gather",
+    "stitch_step_local", "stitch_gather_local", "spmv_ell",
+})
+TPU_REFUSAL = "NotImplementedError: Only 2D gather is supported"
+
+# XLA lays a 1-D 32-bit array out in 1024-element tiles on TPU; a Pallas
+# block over such an array must be a multiple of it (or the whole array).
+TPU_1D_TILE = 1024
+
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """The Pallas execution mode for this process's backend.
+
+    ``None`` resolves to ``True`` (the Pallas interpreter) on the CPU
+    backend and to ``False`` (compiled by Mosaic) on TPU; any other backend
+    is an error. An explicit ``True`` on TPU is refused: it would run the
+    interpreter on the host while the chip idles. An explicit ``False`` is
+    always honoured — compile rehearsals lower kernels for a described TPU
+    from a CPU process.
+    """
+    backend = jax.default_backend()
+    if interpret is None:
+        if backend == "cpu":
+            return True
+        if backend == "tpu":
+            return False
+        raise RuntimeError(
+            f"Pallas kernels run compiled on TPU or interpreted on CPU; "
+            f"backend {backend!r} is neither")
+    if interpret and backend == "tpu":
+        raise ValueError(
+            "interpret=True on a TPU backend would run the Pallas "
+            "interpreter on the host; leave interpret=None to compile")
+    return bool(interpret)
+
+
+def _compiles(kernel: str, interpret: bool) -> bool:
+    """Whether ``kernel`` can run in the resolved mode: everything runs in
+    the interpreter, and compiled mode excludes :data:`TPU_REFUSED`."""
+    return interpret or kernel not in TPU_REFUSED
+
+
+def _require_compiles(kernel: str, interpret: bool) -> None:
+    if not _compiles(kernel, interpret):
+        raise NotImplementedError(
+            f"Pallas kernel {kernel!r} does not compile for TPU: Mosaic "
+            f"refuses its in-kernel gather ({TPU_REFUSAL}). Use impl='xla' "
+            f"or impl='ref' on this backend")
+
+
+def _tpu_block(block: int, size: int) -> int:
+    """A 1-D block length Mosaic accepts over a ``size``-long array: the
+    whole array, or a multiple of :data:`TPU_1D_TILE`."""
+    return min(size, -(-block // TPU_1D_TILE) * TPU_1D_TILE)
+
+
 # VMEM the resident frog_step kernel may spend on its graph block before
 # impl="auto" switches to the HBM-streaming kernel (half a 16 MB core,
 # leaving room for the frog tiles and double buffers).
@@ -37,6 +104,14 @@ def resident_graph_bytes(n: int, nnz: int) -> int:
     """VMEM bytes the resident ``frog_step`` kernel pins for the graph
     (row_ptr + col_idx + deg, int32)."""
     return 4 * ((n + 1) + nnz + n)
+
+
+def step_streams(impl: str, n: int, nnz: int,
+                 vmem_budget: int = STREAM_VMEM_BUDGET) -> bool:
+    """Whether :func:`frog_step` with ``impl`` over an ``(n, nnz)`` graph
+    given a ``BlockedCSR`` runs the streamed kernel (and so reads it)."""
+    return impl == "stream" or (
+        impl == "auto" and resident_graph_bytes(n, nnz) > vmem_budget)
 
 
 def _rng_mode(rng: str, interpret: bool, seed):
@@ -80,7 +155,8 @@ def _pad_to(x: jnp.ndarray, m: int, axis: int = 0, value=0):
 
 
 def spmv(ell: EllGraph, x: jnp.ndarray, impl: str = "pallas",
-         interpret: bool = True, row_block: int = 128) -> jnp.ndarray:
+         interpret: Optional[bool] = None,
+         row_block: int = 128) -> jnp.ndarray:
     """Hybrid-ELL SpMV: y = P @ x (slab kernel + COO spill tail).
 
     ``x`` must have length ≥ max referenced vertex id; output has
@@ -89,6 +165,8 @@ def spmv(ell: EllGraph, x: jnp.ndarray, impl: str = "pallas",
     idx = _pad_to(ell.idx, row_block)
     w = _pad_to(ell.weight, row_block)
     if impl == "pallas":
+        interpret = resolve_interpret(interpret)
+        _require_compiles("spmv_ell", interpret)
         y = spmv_ell_slab(idx, w, x, row_block=row_block, interpret=interpret)
         y = y[: ell.n_rows]
     elif impl == "ref":
@@ -102,7 +180,7 @@ def spmv(ell: EllGraph, x: jnp.ndarray, impl: str = "pallas",
 
 
 def frog_count(dest: jnp.ndarray, n: int, impl: str = "pallas",
-               interpret: bool = True, vertex_block: int = 512,
+               interpret: Optional[bool] = None, vertex_block: int = 1024,
                frog_block: int = 1024,
                assume_sorted: bool = False) -> jnp.ndarray:
     """Histogram of frog destinations into n vertex bins (int32).
@@ -118,6 +196,10 @@ def frog_count(dest: jnp.ndarray, n: int, impl: str = "pallas",
     * ``auto``   — picks by the work model: one-hot tile work
       ``N · ⌈n/vertex_block⌉`` vs sort work ``(N+n) · ⌈log₂N⌉`` (always
       ``sort`` when the input is already sorted).
+
+    Both block lengths are rounded up to a multiple of :data:`TPU_1D_TILE`
+    (or the whole array), the blocking Mosaic accepts; interpreted and
+    compiled runs share it.
     """
     if impl == "auto":
         N = dest.shape[0]
@@ -131,12 +213,10 @@ def frog_count(dest: jnp.ndarray, n: int, impl: str = "pallas",
         return kref.frog_count_sort(dest, n, assume_sorted=assume_sorted)
     if impl != "pallas":
         raise ValueError(f"unknown impl {impl!r}")
-    vertex_block = min(vertex_block, n)
+    interpret = resolve_interpret(interpret)
+    vertex_block = _tpu_block(vertex_block, n)
+    frog_block = _tpu_block(frog_block, max(8, dest.shape[0]))
     n_pad = ((n + vertex_block - 1) // vertex_block) * vertex_block
-    # Padded frogs land on bin n_pad-1? No: route them to an existing bin and
-    # subtract. Simpler: pad with vertex id `n_pad` mapped into a discard bin.
-    N = dest.shape[0]
-    frog_block = min(frog_block, max(8, N))
     dest_p = _pad_to(dest, frog_block, value=-1)  # -1 never matches a bin
     counts = _frog_count(dest_p, n_pad, vertex_block=vertex_block,
                          frog_block=frog_block, interpret=interpret)
@@ -208,7 +288,7 @@ def frog_step(
     deg: jnp.ndarray,
     n: int,
     impl: str = "pallas",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     vertex_block: int = 512,
     frog_block: int = 1024,
     blocked: Optional[BlockedCSR] = None,
@@ -228,7 +308,8 @@ def frog_step(
     * ``ref``    — pure-jnp oracle.
     * ``auto``   — ``pallas`` while ``resident_graph_bytes(n, nnz)`` fits
       ``vmem_budget``, else ``stream`` (falling back to ``pallas`` when no
-      ``blocked`` layout is available from traced arrays).
+      ``blocked`` layout is available from traced arrays); ``ref`` where
+      the chosen kernel does not compile (both, on TPU).
 
     ``rng="device"`` (compiled TPU only) draws the slot bits in-kernel with
     ``pltpu.prng_random_bits`` seeded from ``seed`` — ``bits`` may then be
@@ -238,18 +319,23 @@ def frog_step(
     Handles all padding here so callers pass natural shapes.
     """
     die = die.astype(jnp.int32)
+    interpret = resolve_interpret(interpret)
     use_device_rng, seed_arr = _rng_mode(rng, interpret, seed)
     if not use_device_rng:
         bits = jnp.abs(bits).astype(jnp.int32)
     if impl == "auto":
-        fits = resident_graph_bytes(n, col_idx.shape[0]) <= vmem_budget
         traced = blocked is None and isinstance(row_ptr, jax.core.Tracer)
-        impl = "pallas" if (fits or traced) else "stream"
+        streams = step_streams("auto", n, col_idx.shape[0], vmem_budget)
+        impl = "stream" if streams and not traced else "pallas"
+        kernel = "frog_step" if impl == "pallas" else "frog_step_stream"
+        if not _compiles(kernel, interpret):
+            impl = "ref"
     if impl == "ref":
         if use_device_rng:
             raise ValueError('rng="device" has no jnp oracle (impl="ref")')
         return kref.frog_step_ref(pos, die, bits, row_ptr, col_idx, deg, n)
     if impl == "stream":
+        _require_compiles("frog_step_stream", interpret)
         if blocked is None:
             if isinstance(row_ptr, jax.core.Tracer):
                 raise ValueError(
@@ -263,6 +349,7 @@ def frog_step(
                                  seed_arr=seed_arr)
     if impl != "pallas":
         raise ValueError(f"unknown impl {impl!r}")
+    _require_compiles("frog_step", interpret)
     N = pos.shape[0]
     vertex_block = min(vertex_block, max(8, n))
     n_pad = ((n + vertex_block - 1) // vertex_block) * vertex_block
@@ -287,7 +374,7 @@ def stitch_step(
     endpoints: jnp.ndarray,  # int32[n, R] — walk-segment endpoint slab
     n: int,
     impl: str = "pallas",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     vertex_block: int = 512,
     walk_block: int = 1024,
     rng: str = "caller",
@@ -312,6 +399,7 @@ def stitch_step(
     the tallying kernel's.
     """
     stop = stop.astype(jnp.int32)
+    interpret = resolve_interpret(interpret)
     use_device_rng, seed_arr = _rng_mode(rng, interpret, seed)
     if not use_device_rng:
         bits = jnp.abs(bits).astype(jnp.int32)
@@ -324,6 +412,7 @@ def stitch_step(
         return kref.stitch_step_ref(pos, stop, bits, endpoints, n)
     if impl != "pallas":
         raise ValueError(f"unknown impl {impl!r}")
+    _require_compiles("stitch_step" if tally else "stitch_gather", interpret)
     W = pos.shape[0]
     R = endpoints.shape[1]
     if not tally:
@@ -357,7 +446,7 @@ def stitch_step_local(
     block: jnp.ndarray,      # int32[shard_size, R] — one shard's slab block
     base,                    # int — first global vertex this shard owns
     impl: str = "pallas",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     vertex_block: int = 512,
     walk_block: int = 1024,
     rng: str = "caller",
@@ -378,6 +467,7 @@ def stitch_step_local(
     counts — the wave histograms once over its final positions.
     """
     stop = stop.astype(jnp.int32)
+    interpret = resolve_interpret(interpret)
     use_device_rng, seed_arr = _rng_mode(rng, interpret, seed)
     if not use_device_rng:
         bits = jnp.abs(bits).astype(jnp.int32)
@@ -395,6 +485,8 @@ def stitch_step_local(
         return kref.stitch_step_local_ref(pos, stop, bits, block, base_arr)
     if impl != "pallas":
         raise ValueError(f"unknown impl {impl!r}")
+    _require_compiles(
+        "stitch_step_local" if tally else "stitch_gather_local", interpret)
     W = pos.shape[0]
     sz, R = block.shape
     if not tally:
@@ -427,7 +519,7 @@ def attention(
     q_offset: int = 0,
     soft_cap: Optional[float] = None,
     impl: str = "jnp_flash",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     block_q: int = 128,
     block_k: int = 128,
     chunk: int = 512,
@@ -447,6 +539,7 @@ def attention(
         return kref.attention_chunked(q, k, v, causal=causal, window=window,
                                       q_offset=q_offset, logit_soft_cap=soft_cap,
                                       chunk=chunk)
+    interpret = resolve_interpret(interpret)
     Sq, Skv = q.shape[2], k.shape[2]
     bq, bk = min(block_q, Sq), min(block_k, Skv)
     qp = _pad_to(q, bq, axis=2)

@@ -51,7 +51,8 @@ def spmv_ell_slab(
     weight: jnp.ndarray,     # f32[rows, K]
     x: jnp.ndarray,          # f32[n_pad]
     row_block: int = DEFAULT_ROW_BLOCK,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     rows, K = idx.shape
     if rows % row_block != 0:

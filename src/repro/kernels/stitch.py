@@ -105,7 +105,8 @@ def stitch_step(
     n_pad: int,              # counts bins, multiple of vertex_block
     vertex_block: int = DEFAULT_VERTEX_BLOCK,
     walk_block: int = DEFAULT_WALK_BLOCK,
-    interpret: bool = True,
+    *,
+    interpret: bool,
     use_device_rng: bool = False,
 ):
     """Returns ``(next_pos int32[W], stop_counts int32[n_pad])``."""
@@ -164,7 +165,8 @@ def stitch_gather(
     endpoints: jnp.ndarray,  # int32[n · R] — flat walk-segment endpoint slab
     R: int,
     walk_block: int = DEFAULT_WALK_BLOCK,
-    interpret: bool = True,
+    *,
+    interpret: bool,
     use_device_rng: bool = False,
 ):
     """Gather-only stitch round → ``next_pos int32[W]``.
@@ -257,7 +259,8 @@ def stitch_gather_local(
     R: int,
     shard_size: int,
     walk_block: int = DEFAULT_WALK_BLOCK,
-    interpret: bool = True,
+    *,
+    interpret: bool,
     use_device_rng: bool = False,
 ):
     """Gather-only per-shard stitch round → ``next_contrib int32[W]``.
@@ -308,7 +311,8 @@ def stitch_step_local(
     sz_pad: int,             # local counts bins, multiple of vertex_block
     vertex_block: int = DEFAULT_VERTEX_BLOCK,
     walk_block: int = DEFAULT_WALK_BLOCK,
-    interpret: bool = True,
+    *,
+    interpret: bool,
     use_device_rng: bool = False,
 ):
     """Per-shard stitch round against a local slab block.

@@ -265,8 +265,10 @@ def _segment_walk_rows(row_ptr, col_idx, deg, n, step_impl, R, L,
     """
     C = vertices.shape[0]
     row_keys = jax.vmap(lambda v: jax.random.fold_in(key, v))(vertices)
-    pos0 = jnp.repeat(vertices.astype(jnp.int32), R,
-                      total_repeat_length=C * R)
+    # each row's start vertex, once per slot (a broadcast: jnp.repeat
+    # lowers to a scatter whose TPU compile time grows with C)
+    pos0 = jnp.broadcast_to(vertices.astype(jnp.int32)[:, None],
+                            (C, R)).reshape(-1)
     mask0 = jnp.zeros((pos0.shape[0], _MASK_WORDS), jnp.uint32)
 
     def step(carry, l):
@@ -275,8 +277,12 @@ def _segment_walk_rows(row_ptr, col_idx, deg, n, step_impl, R, L,
         bits = jax.vmap(
             lambda kk: jax.random.randint(kk, (R,), 0, 1 << 30, jnp.int32)
         )(ks)
-        nxt = _segment_step(row_ptr, col_idx, deg, n, step_impl, pos,
-                            bits.reshape(-1))
+        # Materialize the bits before the hop: fused into the gathers'
+        # indices, the per-row threefry makes the TPU compile time grow
+        # with C (302 s for one LiveJournal-sized build shard compiled for
+        # v5e, about 30 s with the barrier).
+        bits = jax.lax.optimization_barrier(bits.reshape(-1))
+        nxt = _segment_step(row_ptr, col_idx, deg, n, step_impl, pos, bits)
         oh = _block_one_hot(nxt, block_size, _MASK_WORDS)
         mask = jnp.where(l < L - 1, mask | oh, mask)
         return (nxt, mask), None
@@ -415,7 +421,7 @@ def _build_walk_index_sharded(
     Each device walks its own range shard's ``shard_size · R`` segment
     frogs and materializes only its ``[shard_size, R]`` slab block
     (``out_specs=P(axis_name)`` — device memory holds ``4nR/S`` bytes of
-    slab). The graph CSR is closed over (replicated); randomness is the
+    slab). The graph CSR is a replicated operand; randomness is the
     per-vertex key stream (``fold_in(key, v)`` inside the shared walk
     program — see the module docstring), so a shard's block is
     byte-identical to the host loop's and to any row-subset rebuild,
@@ -437,9 +443,8 @@ def _build_walk_index_sharded(
     sz = part.shard_size
     R, L = cfg.segments_per_vertex, cfg.segment_len
     bs = segment_mask_block_size(g.n)
-    row_ptr, col_idx, deg = gp.row_ptr, gp.col_idx, gp.out_deg
 
-    def body(key_data):
+    def body(row_ptr, col_idx, deg, key_data):
         k = jax.random.wrap_key_data(key_data, impl="threefry2x32")
         me = jax.lax.axis_index(axis_name)
         vs = me * sz + jnp.arange(sz, dtype=jnp.int32)
@@ -450,9 +455,12 @@ def _build_walk_index_sharded(
     # check_vma=False: jax has no replication rule for pallas_call, and the
     # fused step backends lower through one (the body is trivially
     # per-shard — nothing cross-device to check).
-    fn = rt.sharded_call(body, num_sharded=0, num_replicated=1,
+    fn = rt.sharded_call(body, num_sharded=0, num_replicated=4,
                          num_outputs=2, check_vma=False)
-    ep, mk = fn(ShardRuntime.key_data(key))
+    # the CSR is a replicated operand, not a constant baked into the program
+    csr = jax.device_put((gp.row_ptr, gp.col_idx, gp.out_deg),
+                         rt.sharding(replicated=True))
+    ep, mk = fn(*csr, ShardRuntime.key_data(key))
     blocks = np.asarray(ep)                       # [S, sz, R]
     masks = np.asarray(mk).astype(np.uint32)      # [S, sz, R, W]
     g_epoch = int(getattr(g, "epoch", 0))

@@ -91,6 +91,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import functools
 import math
 import random
 import time
@@ -391,7 +392,7 @@ class QueryScheduler:
         self._lost = np.zeros(
             index.num_shards if isinstance(index, ShardedWalkIndex) else 1,
             bool)
-        self._placed_blocks = None
+        self._placed_blocks = self._placed_csr = None
         if isinstance(index, ShardedWalkIndex):
             self.runtime = (runtime if runtime is not None
                             else ShardRuntime.acquire(index.num_shards))
@@ -414,6 +415,11 @@ class QueryScheduler:
                 # block — 4nR/S bytes of slab, never the whole thing).
                 self._placed_blocks = self.runtime.place_sharded(
                     jnp.asarray(index.blocks))
+                # the CSR rides into the mesh program as a replicated
+                # operand, not as constants baked into its executable
+                self._placed_csr = jax.device_put(
+                    (g.row_ptr, g.col_idx, g.out_deg),
+                    self.runtime.sharding(replicated=True))
             else:
                 self.dispatch = sharded_dispatch   # "fused" | legacy "loop"
         else:
@@ -604,13 +610,14 @@ class QueryScheduler:
         ax = rt.axis_name
         spec = self._spec(W_b, Q_b)
 
-        def body(blocks, start, uniform, qid, t_cap, key_data, lost):
+        def body(blocks, row_ptr, col_idx, deg, start, uniform, qid, t_cap,
+                 key_data, lost):
             record_wave_trace(spec)
             block_flat = blocks[0].reshape(-1)
             base = jax.lax.axis_index(ax) * sz
             key = jax.random.wrap_key_data(key_data, impl="threefry2x32")
             pos, q, s0 = wave_prep(
-                g.row_ptr, g.col_idx, g.out_deg, start, uniform, t_cap,
+                row_ptr, col_idx, deg, start, uniform, t_cap,
                 key, n=g.n, L=index.segment_len, p_T=self.p_T)
             alive = jnp.ones(pos.shape, bool)
 
@@ -639,14 +646,15 @@ class QueryScheduler:
         # check_vma=False: the fused stitch backends lower through
         # pallas_call (no replication rule), and the body mixes replicated
         # walk state with per-shard slab blocks by construction. Donation
-        # skips the blocks (operand 0, reused every wave) and key_data.
-        donate = (1, 2, 3, 4, 6) if self.donate_wave_buffers else ()
-        fn = rt.sharded_call(body, num_sharded=1, num_replicated=6,
+        # skips the blocks and the CSR (operands 0-3, reused every wave)
+        # and key_data.
+        donate = (4, 5, 6, 7, 9) if self.donate_wave_buffers else ()
+        fn = rt.sharded_call(body, num_sharded=1, num_replicated=9,
                              check_vma=False, donate_argnums=donate)
-        blocks = self._placed_blocks
+        blocks, csr = self._placed_blocks, self._placed_csr
 
         def wave(start, uniform, qid, t_cap, key, lost):
-            out = np.asarray(fn(blocks, start, uniform, qid, t_cap,
+            out = np.asarray(fn(blocks, *csr, start, uniform, qid, t_cap,
                                 ShardRuntime.key_data(key),
                                 lost))              # [S, Q, sz]
             return out.transpose(1, 0, 2).reshape(Q, -1)[:, : self.g.n]
@@ -665,19 +673,18 @@ class QueryScheduler:
         S = rt.num_shards
         sz = index.shard_size
 
-        def _prep(start, uniform, t_cap, key):
-            return wave_prep(g.row_ptr, g.col_idx, g.out_deg, start,
-                             uniform, t_cap, key, n=g.n,
-                             L=index.segment_len, p_T=self.p_T)
-
-        prep = jax.jit(_prep)
+        # the CSR is an operand: closed over, it would be baked into the
+        # executable as constants
+        prep = jax.jit(functools.partial(
+            wave_prep, n=g.n, L=index.segment_len, p_T=self.p_T))
         round_s = jax.jit(self._shard_round)
         tally_s = jax.jit(self._shard_tally, static_argnums=3)
         blocks = [jnp.asarray(index.blocks[s].reshape(-1))
                   for s in range(rt.num_shards)]
 
         def wave(start, uniform, qid, t_cap, key, lost):
-            pos, q, s0 = prep(start, uniform, t_cap, key)
+            pos, q, s0 = prep(g.row_ptr, g.col_idx, g.out_deg, start,
+                              uniform, t_cap, key)
             lost_host = np.asarray(lost)
 
             def round_fn(pos, j):
@@ -1060,7 +1067,7 @@ class QueryScheduler:
                                     mesh=None)
         self.dispatch = "fused"
         self._wave_fns.clear()      # drop the mesh programs
-        self._placed_blocks = None
+        self._placed_blocks = self._placed_csr = None
         self.fault_log.append(FaultEvent(
             kind="failover", wave=wave_no,
             detail=f"mesh dispatch abandoned for single-device fused "

@@ -46,8 +46,7 @@ from repro.checkpoint import CheckpointCorruptError
 from repro.config import (EngineConfig, FrogWildConfig, KernelConfig,
                           RuntimeConfig, ServingConfig, ShardConfig,
                           WalkIndexConfig)
-from repro.core.frogwild import (FrogWildResult, _as_tuple,
-                                 _frogwild_walks)
+from repro.core.frogwild import frogwild_walks
 from repro.distributed.faults import FaultInjector, WaveFailedError
 from repro.distributed.runtime import ShardRuntime
 from repro.engine import gas as _gas
@@ -122,7 +121,7 @@ def batch_pagerank(
     cfg = config.frogwild() if isinstance(config, RuntimeConfig) else config
     if key is None:
         key = jax.random.PRNGKey(0 if seed is None else seed)
-    return _frogwild_walks(graph, cfg, key)
+    return frogwild_walks(graph, cfg, key)
 
 
 def build_index(
@@ -636,11 +635,7 @@ class FrogWildService:
         if key is None:
             key = jax.random.PRNGKey(rc.runtime.seed if seed is None
                                      else seed)
-        run = jax.jit(
-            lambda kk: _as_tuple(_frogwild_walks(self.graph, cfg, kk)))
-        counts, pi_hat = run(key)
-        return FrogWildResult(counts=counts, pi_hat=pi_hat,
-                              num_frogs=cfg.num_frogs)
+        return frogwild_walks(self.graph, cfg, key)
 
     def _dgraph(self, rc: RuntimeConfig) -> "_gas.DistributedGraph":
         """Per-shard CSR blocks for the engine path (cached per shape)."""

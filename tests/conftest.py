@@ -6,6 +6,7 @@ import sys
 import warnings
 
 import pytest
+from hypothesis import settings
 
 # Donation of per-wave walk-state operands leaves the [Q+1, n] tally output
 # unable to alias the [W] donated inputs — expected, not a leak (see
@@ -20,18 +21,7 @@ def pytest_configure(config):
         "filterwarnings",
         "ignore:Some donated buffers were not usable")
 
-try:
-    from hypothesis import settings
-except ModuleNotFoundError:
-    # No network in this container: fall back to the vendored deterministic
-    # example sweep (tests/_hypothesis_fallback.py) so the suite still runs.
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from _hypothesis_fallback import install
-
-    install()
-    from hypothesis import settings
-
-# CPU container: keep hypothesis fast and deadline-free.
+# Keep hypothesis fast and deadline-free on the CPU backend.
 settings.register_profile("ci", max_examples=20, deadline=None)
 settings.load_profile("ci")
 

@@ -98,6 +98,16 @@ def test_power_iteration_fixed_point():
     assert float(pi.min()) >= 0.15 / g.n * 0.99   # teleport floor
 
 
+@pytest.mark.parametrize("n", [500, 777])
+def test_power_iteration_ell_matches_coo(n):
+    """The hybrid-ELL Pallas SpMV path (slab kernel + hub spill tail) runs
+    the same power iteration as the COO segment-sum path."""
+    g = chung_lu_powerlaw(n=n, avg_out_deg=8, seed=5)
+    ell = power_iteration(g, num_iters=50, spmv="ell")
+    coo = power_iteration(g, num_iters=50, spmv="coo")
+    np.testing.assert_allclose(np.asarray(ell), np.asarray(coo), atol=1e-5)
+
+
 def test_power_iteration_matches_dense_eig():
     g = ring_of_cliques(3, 4)
     pi = power_iteration(g, num_iters=200)
@@ -141,3 +151,32 @@ def test_theory_sampling_term_monotone(N, k):
 def test_theory_suggestions_sane():
     assert theory.suggested_steps(0.1) >= 1
     assert theory.suggested_frogs(100, 0.3) >= 100
+
+
+_WALK = dict(num_frogs=1500, num_steps=10)
+
+
+@pytest.mark.parametrize("cfg", [
+    FrogWildConfig(**_WALK),
+    FrogWildConfig(**_WALK, step_impl="stream"),
+    FrogWildConfig(**_WALK, p_s=0.6, erasure="channel", num_shards=4,
+                   draw="rejection"),
+    FrogWildConfig(**_WALK, p_s=0.6, erasure="channel", num_shards=4,
+                   draw="cumsum"),
+    FrogWildConfig(**_WALK, p_s=0.6, erasure="independent", draw="cumsum"),
+], ids=["plain", "stream", "channel-rejection", "channel-cumsum",
+        "independent-cumsum"])
+def test_walker_with_graph_operands_matches_closed_over_graph(cfg):
+    """``frogwild_walks`` passes the CSR and the layouts derived from it as
+    operands; the answer is the one of the same walk with the concrete
+    graph closed over."""
+    from repro.core.frogwild import _as_tuple, _frogwild_walks, frogwild_walks
+
+    g = chung_lu_powerlaw(n=400, avg_out_deg=6, seed=5)
+    key = jax.random.PRNGKey(7)
+    counts, pi_hat = jax.jit(
+        lambda k: _as_tuple(_frogwild_walks(g, cfg, k)))(key)
+    res = frogwild_walks(g, cfg, key)
+    assert np.array_equal(np.asarray(res.counts), np.asarray(counts))
+    assert np.array_equal(np.asarray(res.pi_hat), np.asarray(pi_hat))
+    assert int(np.asarray(res.counts).sum()) == cfg.num_frogs

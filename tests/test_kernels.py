@@ -10,6 +10,76 @@ from repro.kernels import ops, ref
 
 
 # ---------------------------------------------------------------------------
+# Pallas mode follows the backend
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("backend,interpret,want", [
+    ("cpu", None, True), ("cpu", True, True), ("cpu", False, False),
+    ("tpu", None, False), ("tpu", False, False),
+])
+def test_resolve_interpret_follows_backend(monkeypatch, backend, interpret,
+                                           want):
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    assert ops.resolve_interpret(interpret) is want
+
+
+def test_resolve_interpret_refuses_host_interpreter_on_tpu(monkeypatch):
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="interpret=True on a TPU"):
+        ops.resolve_interpret(True)
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu' is neither"):
+        ops.resolve_interpret()
+
+
+def test_compiled_mode_refuses_kernels_mosaic_rejects():
+    """Asked for explicitly in compiled mode, a kernel of ops.TPU_REFUSED
+    raises with the compiler's reason instead of failing inside a
+    larger program's lowering."""
+    g = uniform_random(64, avg_out_deg=4, seed=0)
+    pos = jnp.zeros(16, jnp.int32)
+    slab = jnp.zeros((64, 4), jnp.int32)
+    calls = {
+        "frog_step": lambda: ops.frog_step(
+            pos, pos, pos, g.row_ptr, g.col_idx, g.out_deg, g.n,
+            impl="pallas", interpret=False),
+        "frog_step_stream": lambda: ops.frog_step(
+            pos, pos, pos, g.row_ptr, g.col_idx, g.out_deg, g.n,
+            impl="stream", interpret=False),
+        "stitch_step": lambda: ops.stitch_step(
+            pos, pos, pos, slab, 64, interpret=False),
+        "stitch_gather": lambda: ops.stitch_step(
+            pos, pos, pos, slab, 64, interpret=False, tally=False),
+        "stitch_step_local": lambda: ops.stitch_step_local(
+            pos, pos, pos, slab[:32], 0, interpret=False),
+        "stitch_gather_local": lambda: ops.stitch_step_local(
+            pos, pos, pos, slab[:32], 0, interpret=False, tally=False),
+        "spmv_ell": lambda: ops.spmv(to_ell(g, K=8), jnp.ones(64),
+                                     interpret=False),
+    }
+    assert set(calls) == ops.TPU_REFUSED
+    for kernel, call in calls.items():
+        with pytest.raises(NotImplementedError,
+                           match=f"{kernel}.*Only 2D gather is supported"):
+            call()
+
+
+def test_auto_step_avoids_kernels_that_do_not_compile():
+    """impl="auto" in compiled mode picks the jnp path — byte-identical to
+    impl="ref" — because neither fused step kernel compiles for TPU."""
+    g = uniform_random(128, avg_out_deg=5, seed=1)
+    rng = np.random.default_rng(0)
+    pos = jnp.asarray(rng.integers(0, g.n, 64), jnp.int32)
+    die = jnp.asarray(rng.integers(0, 2, 64), jnp.int32)
+    bits = jnp.asarray(rng.integers(0, 1 << 30, 64), jnp.int32)
+    args = (pos, die, bits, g.row_ptr, g.col_idx, g.out_deg, g.n)
+    got = ops.frog_step(*args, impl="auto", interpret=False)
+    want = ops.frog_step(*args, impl="ref")
+    for a, b in zip(got, want):
+        assert (np.asarray(a) == np.asarray(b)).all()
+
+
+# ---------------------------------------------------------------------------
 # SpMV (hybrid ELL)
 # ---------------------------------------------------------------------------
 
