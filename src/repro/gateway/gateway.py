@@ -56,6 +56,7 @@ from typing import Dict, List, Optional, Tuple, Union
 
 import numpy as np
 
+from repro import telemetry
 from repro.config import RuntimeConfig
 from repro.distributed.faults import ReplicaFault, WaveFailedError
 from repro.gateway.cache import CacheKey, ResultCache
@@ -787,7 +788,8 @@ class Gateway:
     def stats(self) -> Dict[str, object]:
         """One structured snapshot of the whole tier (what ``/metrics``
         serves): gateway counters + per-replica scheduler **and
-        supervision** state + cache."""
+        supervision** state + cache, and ``spans``: the per-stage totals
+        of :mod:`repro.telemetry` while it is enabled, else ``None``."""
         snap = self.metrics.snapshot()
         snap["epoch"] = self.epoch
         snap["graph_epoch"] = int(getattr(self.pool.graph, "epoch", 0))
@@ -800,6 +802,7 @@ class Gateway:
         snap["shed_backlog_walks"] = self.shed_backlog_walks
         snap["cache"] = (self.cache.stats() if self.cache is not None
                          else None)
+        snap["spans"] = telemetry.totals() if telemetry.enabled() else None
         replicas = []
         for i, r in enumerate(self.pool.replicas):
             st = r.serving_stats()
@@ -813,6 +816,8 @@ class Gateway:
                 "rejected": 0 if st is None else st.rejected,
                 "waves_run": 0 if st is None else st.waves_run,
                 "walks_executed": 0 if st is None else st.walks_executed,
+                # over waves_run: the tally bytes a wave hands the host
+                "tally_bytes_out": 0 if st is None else st.tally_bytes_out,
                 "wave_occupancy": (0.0 if st is None
                                    else round(st.wave_occupancy, 4)),
                 "wave_time_ema_s": None if st is None else st.wave_time_ema_s,

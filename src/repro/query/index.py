@@ -69,6 +69,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.checkpoint import (CheckpointCorruptError, latest_step,
                               save_checkpoint)
 from repro.config import WalkIndexConfig, warn_deprecated
@@ -368,18 +369,27 @@ def _build_walk_index(
         block_size=segment_mask_block_size(g.n),
     )
     rt = ShardRuntime(num_shards=cfg.num_shards, mesh=None)
-    # per-vertex key streams: every shard gets the same base key — the
-    # vertex id folded inside the walk program is the only stream selector.
-    pairs = rt.map_shards(
-        lambda s: jax.tree_util.tree_map(
-            np.asarray, walker(jnp.int32(part.bounds(s)[0]), key)))
-    endpoints = np.concatenate([p[0] for p in pairs], axis=0)[: g.n]
-    masks = np.concatenate([p[1] for p in pairs], axis=0)[: g.n]
+
+    def shard(s):
+        # per-vertex key streams: every shard gets the same base key — the
+        # vertex id folded inside the walk program is the only selector.
+        with telemetry.span("repro.index.walk"):
+            out = jax.block_until_ready(
+                walker(jnp.int32(part.bounds(s)[0]), key))
+        with telemetry.span("repro.index.copy_out"):
+            return jax.tree_util.tree_map(np.asarray, out)
+
+    pairs = rt.map_shards(shard)
+    with telemetry.span("repro.index.copy_out"):
+        endpoints = jnp.asarray(
+            np.concatenate([p[0] for p in pairs], axis=0)[: g.n], jnp.int32)
+        masks = np.concatenate([p[1] for p in pairs],
+                               axis=0)[: g.n].astype(np.uint32)
     return WalkIndex(
-        endpoints=jnp.asarray(endpoints, dtype=jnp.int32),
+        endpoints=endpoints,
         segment_len=cfg.segment_len,
         seed=cfg.seed,
-        visited_blocks=masks.astype(np.uint32),
+        visited_blocks=masks,
         graph_epoch=int(getattr(g, "epoch", 0)),
         mutation_offset=int(getattr(g, "mutation_offset", 0)),
     )
@@ -460,9 +470,11 @@ def _build_walk_index_sharded(
     # the CSR is a replicated operand, not a constant baked into the program
     csr = jax.device_put((gp.row_ptr, gp.col_idx, gp.out_deg),
                          rt.sharding(replicated=True))
-    ep, mk = fn(*csr, ShardRuntime.key_data(key))
-    blocks = np.asarray(ep)                       # [S, sz, R]
-    masks = np.asarray(mk).astype(np.uint32)      # [S, sz, R, W]
+    with telemetry.span("repro.index.walk"):
+        ep, mk = jax.block_until_ready(fn(*csr, ShardRuntime.key_data(key)))
+    with telemetry.span("repro.index.copy_out"):
+        blocks = np.asarray(ep)                       # [S, sz, R]
+        masks = np.asarray(mk).astype(np.uint32)      # [S, sz, R, W]
     g_epoch = int(getattr(g, "epoch", 0))
     g_offset = int(getattr(g, "mutation_offset", 0))
     if directory is not None:
@@ -475,7 +487,10 @@ def _build_walk_index_sharded(
                                segment_len=cfg.segment_len, seed=cfg.seed,
                                visited_blocks=masks, graph_epoch=g_epoch,
                                mutation_offset=g_offset)
-    return sharded.reassemble() if reassemble else sharded
+    if not reassemble:
+        return sharded
+    with telemetry.span("repro.index.copy_out"):
+        return sharded.reassemble()
 
 
 # --- persistence (checkpoint/ atomic step directories) ----------------------

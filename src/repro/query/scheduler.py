@@ -101,6 +101,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.config import warn_deprecated
 from repro.core import theory
 from repro.distributed.faults import (FaultEvent, FaultInjector, ShardFault,
@@ -279,11 +280,10 @@ class SchedulerStats:
     lost_shards: Tuple[int, ...]
     max_walks: int
     max_queries: int
-    # heartbeat (PR 8): when the last wave retired and how long it took —
-    # the pool supervisor's stall-detection + health-scoring inputs.
-    t_last_wave: Optional[float] = None   # time.monotonic() of last wave
-    last_wave_s: Optional[float] = None   # wall time of that wave
     epoch: int = 0                   # graph epoch this scheduler serves
+    # bytes of wave tally brought to the host, summed over waves; per wave
+    # it is what the dense ``[Q_b, n]`` tally costs the host to take in
+    tally_bytes_out: int = 0
 
 
 @dataclasses.dataclass
@@ -374,8 +374,7 @@ class QueryScheduler:
         self._waves_run = 0
         self._walks_allocated = 0    # walk slots handed out across all waves
         self._walks_executed = 0     # walks whose tallies actually landed
-        self._t_last_wave: Optional[float] = None   # heartbeat stamp
-        self._last_wave_s: Optional[float] = None   # last wave wall time
+        self._tally_bytes_out = 0    # tally bytes copied device → host
         # --- fault-tolerance state (PR 6) ---
         self._injector = fault_injector
         self.wave_timeout_s = wave_timeout_s
@@ -475,8 +474,11 @@ class QueryScheduler:
 
     def _wave_for(self, W_b: int, Q_b: int):
         """The wave callable for one ladder bucket, built on first use and
-        cached — ``wave(start, uniform, qid, t_cap, key, lost) ->
-        int32[Q_b, n]`` with every operand at bucket shape."""
+        cached — ``wave(start, uniform, qid, t_cap, key, lost)`` with every
+        operand at bucket shape. It returns the tally as the program left
+        it: a device ``int32[Q_b, n]`` (fused), a device ``[S, Q_b, sz]``
+        (mesh), or the host loop's stacked ``[S, Q_b, sz]``;
+        :meth:`_tally_to_host` brings it to the host in vertex order."""
         fn = self._wave_fns.get((W_b, Q_b))
         if fn is None:
             if self.dispatch == "mesh":
@@ -499,10 +501,11 @@ class QueryScheduler:
         for W_b in self._walk_ladder:
             for Q_b in self._query_ladder:
                 wave = self._wave_for(W_b, Q_b)
-                wave(jnp.zeros(W_b, jnp.int32), jnp.zeros(W_b, bool),
-                     jnp.full(W_b, Q_b, jnp.int32),
-                     jnp.zeros(W_b, jnp.int32), key,
-                     jnp.asarray(self._lost))
+                jax.block_until_ready(wave(
+                    jnp.zeros(W_b, jnp.int32), jnp.zeros(W_b, bool),
+                    jnp.full(W_b, Q_b, jnp.int32),
+                    jnp.zeros(W_b, jnp.int32), key,
+                    jnp.asarray(self._lost)))
                 count += 1
         return count
 
@@ -518,10 +521,9 @@ class QueryScheduler:
         g = self.g
 
         def wave(start, uniform, qid, t_cap, key, lost):
-            return np.asarray(prog(
-                self._slab_flat, g.row_ptr, g.col_idx, g.out_deg,
-                start, uniform, qid, t_cap,
-                ShardRuntime.key_data(key), lost))
+            return prog(self._slab_flat, g.row_ptr, g.col_idx, g.out_deg,
+                        start, uniform, qid, t_cap,
+                        ShardRuntime.key_data(key), lost)
 
         return wave
 
@@ -654,10 +656,8 @@ class QueryScheduler:
         blocks, csr = self._placed_blocks, self._placed_csr
 
         def wave(start, uniform, qid, t_cap, key, lost):
-            out = np.asarray(fn(blocks, *csr, start, uniform, qid, t_cap,
-                                ShardRuntime.key_data(key),
-                                lost))              # [S, Q, sz]
-            return out.transpose(1, 0, 2).reshape(Q, -1)[:, : self.g.n]
+            return fn(blocks, *csr, start, uniform, qid, t_cap,
+                      ShardRuntime.key_data(key), lost)    # [S, Q, sz]
 
         return wave
 
@@ -700,11 +700,10 @@ class QueryScheduler:
             lost_of = lambda p: lost[jnp.clip(p // sz, 0, S - 1)]
             pos, alive = self._stitch_rounds(pos, q, round_fn, lost_of)
             qid_eff = jnp.where(alive, qid, Q)   # dead walks → discard bin
-            out = np.stack([
+            return np.stack([
                 np.zeros((Q, sz), np.int32) if lost_host[s]
                 else np.asarray(tally_s(pos, qid_eff, jnp.int32(s * sz), Q))
-                for s in range(S)])
-            return out.transpose(1, 0, 2).reshape(Q, -1)[:, : self.g.n]
+                for s in range(S)])                          # [S, Q, sz]
 
         return wave
 
@@ -861,32 +860,44 @@ class QueryScheduler:
         host-side scheduler state, so every dispatch path and replica picks
         the same bucket — the cross-path byte-identity contract holds
         bucket by bucket.
+
+        Stages (:mod:`repro.telemetry` spans): ``repro.scheduler.admit``,
+        ``repro.wave.prepare``, then per attempt ``repro.wave.launch`` /
+        ``.device_wait`` / ``.d2h``, then ``repro.scheduler.accumulate``
+        and one ``repro.scheduler.finalize`` per answer, all inside
+        ``repro.scheduler.step_wave``.
         """
-        self._admit()
+        with telemetry.span("repro.scheduler.step_wave"):
+            return self._step_wave()
+
+    def _step_wave(self) -> bool:
+        with telemetry.span("repro.scheduler.admit"):
+            self._admit()
         if not self.active:
             return False
-        alloc = self._allocate()
-        W_b = self._bucket(self._walk_ladder, sum(alloc.values()))
-        Q_b = self._bucket(self._query_ladder, len(alloc))
-        start = np.zeros(W_b, np.int32)
-        uniform = np.zeros(W_b, bool)
-        qid = np.full(W_b, Q_b, np.int32)    # default: discard bin
-        t_cap = np.zeros(W_b, np.int32)
-        cursor = 0
-        # ``alloc`` preserves EDF order, so compact row ci is deterministic
-        # from (deadlines, slots) alone — identical across dispatch paths.
-        for ci, (s, w) in enumerate(alloc.items()):
-            a = self.active[s]
-            sl = slice(cursor, cursor + w)
-            qid[sl] = ci
-            t_cap[sl] = a.plan.num_steps
-            if a.req.kind == "ppr":
-                start[sl] = a.req.source
-            else:
-                uniform[sl] = True
-            cursor += w
-
-        self._key, k_wave = jax.random.split(self._key)
+        with telemetry.span("repro.wave.prepare"):
+            alloc = self._allocate()
+            W_b = self._bucket(self._walk_ladder, sum(alloc.values()))
+            Q_b = self._bucket(self._query_ladder, len(alloc))
+            start = np.zeros(W_b, np.int32)
+            uniform = np.zeros(W_b, bool)
+            qid = np.full(W_b, Q_b, np.int32)    # default: discard bin
+            t_cap = np.zeros(W_b, np.int32)
+            cursor = 0
+            # ``alloc`` preserves EDF order, so compact row ci is
+            # deterministic from (deadlines, slots) alone — identical
+            # across dispatch paths.
+            for ci, (s, w) in enumerate(alloc.items()):
+                a = self.active[s]
+                sl = slice(cursor, cursor + w)
+                qid[sl] = ci
+                t_cap[sl] = a.plan.num_steps
+                if a.req.kind == "ppr":
+                    start[sl] = a.req.source
+                else:
+                    uniform[sl] = True
+                cursor += w
+            self._key, k_wave = jax.random.split(self._key)
         counts, clean, dt = self._run_wave(start, uniform, qid, t_cap,
                                            k_wave, W_b, Q_b)
         now = time.perf_counter()
@@ -899,38 +910,42 @@ class QueryScheduler:
         # not the machine), and a clean outlier is clamped to a bounded
         # multiple of the current estimate.
         self._waves_run += 1
-        self._t_last_wave = time.monotonic()
-        self._last_wave_s = dt
         if self._waves_run > 1 and clean:
             if self._wave_time is not None:
                 dt = min(dt, _EMA_OUTLIER_CLAMP * self._wave_time)
             self._wave_time = (dt if self._wave_time is None
                                else 0.5 * self._wave_time + 0.5 * dt)
 
-        for ci, (s, w) in enumerate(alloc.items()):
-            if s not in self.active:         # evicted mid-wave? impossible
-                continue                     # today, but stay defensive
-            a = self.active[s]
-            row = counts[ci]                 # compact row → query slot
-            # every surviving walk lands in exactly one tally bin, so the
-            # slot's landed count is the row sum — lost walks need no extra
-            # program output.
-            landed = int(row.sum())
-            a.counts += row
-            a.remaining -= w
-            a.executed += landed
-            self._walks_executed += landed
-            a.waves += 1
-            if landed < w:
-                a.lost += w - landed
-                a.shards_lost = tuple(sorted(self.lost_shards))
-            early = (a.remaining > 0 and a.req.early_stop
-                     and self.anytime_bound(a.plan.num_steps, a.req.k,
-                                             a.req.delta, a.executed)
-                     <= a.req.epsilon)
-            if a.remaining == 0 or early:
-                self.finished.append(self._finalize(a, now, early=early))
-                del self.active[s]
+        done = []
+        with telemetry.span("repro.scheduler.accumulate"):
+            for ci, (s, w) in enumerate(alloc.items()):
+                if s not in self.active:     # evicted mid-wave? impossible
+                    continue                 # today, but stay defensive
+                a = self.active[s]
+                row = counts[ci]             # compact row → query slot
+                # every surviving walk lands in exactly one tally bin, so
+                # the slot's landed count is the row sum — lost walks need
+                # no extra program output.
+                landed = int(row.sum())
+                a.counts += row
+                a.remaining -= w
+                a.executed += landed
+                self._walks_executed += landed
+                a.waves += 1
+                if landed < w:
+                    a.lost += w - landed
+                    a.shards_lost = tuple(sorted(self.lost_shards))
+                early = (a.remaining > 0 and a.req.early_stop
+                         and self.anytime_bound(a.plan.num_steps, a.req.k,
+                                                a.req.delta, a.executed)
+                         <= a.req.epsilon)
+                if a.remaining == 0 or early:
+                    done.append((s, early))
+        for s, early in done:
+            # the span also covers the release of the slot's accumulator
+            with telemetry.span("repro.scheduler.finalize"):
+                self.finished.append(
+                    self._finalize(self.active.pop(s), now, early=early))
         return True
 
     # --- wave supervision (fault tolerance) -------------------------------
@@ -950,8 +965,10 @@ class QueryScheduler:
         to fresh device buffers, so donation (the executable consumes its
         inputs) can never poison a retry.
 
-        Returns ``(counts, clean, dt)`` — ``clean`` is False for any wave
-        that saw a fault, stall, retry, or eviction (the EMA skips those).
+        Returns ``(counts, clean, dt)`` — ``counts`` the host ``[Q_b, n]``
+        tally, ``clean`` False for any wave that saw a fault, stall, retry,
+        or eviction (the EMA skips those). ``dt`` and the timeout cover the
+        dispatch, the wait for the device and the tally's copy to the host.
         """
         wave_no = self._waves_run
         attempt = 0
@@ -977,11 +994,15 @@ class QueryScheduler:
                         raise ShardFault(
                             f"injected transient fault (wave {wave_no}, "
                             f"attempt {attempt})", transient=True)
-                wave = self._wave_for(W_b, Q_b)
-                counts = wave(
-                    jnp.asarray(start), jnp.asarray(uniform),
-                    jnp.asarray(qid), jnp.asarray(t_cap), k_wave,
-                    jnp.asarray(self._lost))
+                with telemetry.span("repro.wave.launch"):
+                    wave = self._wave_for(W_b, Q_b)
+                    out = wave(
+                        jnp.asarray(start), jnp.asarray(uniform),
+                        jnp.asarray(qid), jnp.asarray(t_cap), k_wave,
+                        jnp.asarray(self._lost))
+                with telemetry.span("repro.wave.device_wait"):
+                    jax.block_until_ready(out)
+                counts = self._tally_to_host(out, Q_b)
                 dt = time.perf_counter() - t0
                 if self.wave_timeout_s is not None and dt > self.wave_timeout_s:
                     raise WaveTimeout(
@@ -1001,6 +1022,18 @@ class QueryScheduler:
             except WaveTimeout as e:
                 clean = False
                 attempt = self._count_retry(wave_no, attempt, e)
+
+    def _tally_to_host(self, out, Q_b: int) -> np.ndarray:
+        """The wave's tally as a host ``int32[Q_b, n]``: one copy off the
+        device (the host loop's shards are on the host already), counted in
+        ``tally_bytes_out``, then shard-major ``[S, Q_b, sz]`` rows put back
+        in vertex order — the one place every dispatch path does this."""
+        with telemetry.span("repro.wave.d2h"):
+            counts = np.asarray(out)
+            self._tally_bytes_out += counts.nbytes
+            if counts.ndim == 3:
+                counts = counts.transpose(1, 0, 2).reshape(Q_b, -1)
+            return counts[:, : self.g.n]
 
     def _count_retry(self, wave_no: int, attempt: int,
                      err: Exception) -> int:
@@ -1163,9 +1196,8 @@ class QueryScheduler:
             lost_shards=tuple(sorted(self.lost_shards)),
             max_walks=self.max_walks,
             max_queries=self.max_queries,
-            t_last_wave=self._t_last_wave,
-            last_wave_s=self._last_wave_s,
             epoch=self.epoch,
+            tally_bytes_out=self._tally_bytes_out,
         )
 
     # --- anytime (ε, δ) refinement ---------------------------------------
