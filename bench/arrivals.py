@@ -5,6 +5,11 @@ A mix file (``traffic/<name>.json``) sets:
 
 - ``loop``: ``"closed"`` (``outstanding`` requests kept in flight),
   ``"open"`` (``rate_per_s`` arrivals, from ``arrivals``) or ``"batch"``;
+- ``max_requests`` (closed loop): the block size of its endless supply
+  (:meth:`Mix.stream`). Block 0 is ``requests(max_requests)``; each later
+  block is drawn when the one before is spent, from a spawn key of its
+  own, so a loop never runs dry whatever rate the system reaches, and
+  every rate sends the same requests as far as it gets;
 - ``request``: every request's ``{"k", "epsilon", "delta"}``, all PPR;
 - ``sources``: ``{"dist": "uniform"}`` (never repeated) or
   ``{"dist": "zipf", "constant": 0.99}`` (YCSB's zipfian over a permutation
@@ -19,12 +24,14 @@ the order of the work and not its amount.
 from __future__ import annotations
 
 import dataclasses
-from typing import List
+from typing import Iterator, List, Optional
 
 import numpy as np
 
-# Independent streams of one seed (numpy SeedSequence spawn keys).
+# Independent streams of one seed (numpy SeedSequence spawn keys). Block i
+# of a closed loop's supply draws from STREAM_SOURCES + BLOCK_STRIDE * i.
 STREAM_SOURCES, STREAM_ARRIVALS, STREAM_WARMUP = 1, 2, 4
+BLOCK_STRIDE = 100
 
 
 def rng(seed: int, stream: int) -> np.random.Generator:
@@ -73,12 +80,54 @@ class Mix:
         of its own, so warm-up traffic never shares the window's sources)."""
         off = self._off + (10 * STREAM_WARMUP if warmup else 0)
         src = self.sources(count, rng(self.seed, off + STREAM_SOURCES))
-        rq = self.params["request"]
-        return [Request(k=int(rq["k"]), epsilon=float(rq["epsilon"]),
-                        delta=float(rq["delta"]), source=int(s))
-                for s in src]
+        return [self._request(s) for s in src]
 
-    def sources(self, count: int, r: np.random.Generator) -> np.ndarray:
+    def stream(self) -> Iterator[Request]:
+        """A closed loop's endless supply, in blocks of ``max_requests``:
+        block 0 is ``requests(max_requests)``, block i draws from stream
+        ``STREAM_SOURCES + BLOCK_STRIDE * i``. ``"uniform"`` sources never
+        repeat within the supply (later blocks leave out every source
+        already sent) and run out only after all ``n``, with an error;
+        ``"zipf"`` keeps block 0's permutation, so the same vertices stay
+        popular."""
+        block = int(self.params["max_requests"])
+        first = self.requests(block)
+        yield from first
+        uniform = self.params["sources"]["dist"] == "uniform"
+        if uniform:
+            free = np.ones(self.n, bool)
+            free[[r.source for r in first]] = False
+        else:
+            perm = rng(self.seed, self._off + STREAM_SOURCES).permutation(
+                self.n)
+        i = 1
+        while True:
+            r = rng(self.seed, self._off + STREAM_SOURCES + BLOCK_STRIDE * i)
+            if uniform:
+                pool = np.flatnonzero(free)
+                if not len(pool):
+                    raise RuntimeError(
+                        f"mix {self.params.get('name', '?')!r}, seed "
+                        f"{self.seed}: all {self.n} sources sent after "
+                        f"{self.n} requests, and uniform sources never repeat")
+                src = r.choice(pool, size=min(block, len(pool)),
+                               replace=False)
+                free[src] = False
+            else:
+                src = self.sources(block, r, perm)
+            for s in src:
+                yield self._request(s)
+            i += 1
+
+    def _request(self, source) -> Request:
+        rq = self.params["request"]
+        return Request(k=int(rq["k"]), epsilon=float(rq["epsilon"]),
+                       delta=float(rq["delta"]), source=int(source))
+
+    def sources(self, count: int, r: np.random.Generator,
+                perm: Optional[np.ndarray] = None) -> np.ndarray:
+        """``count`` sources drawn with ``r``; ``perm``, for ``"zipf"``, the
+        popularity order (else drawn from ``r`` first)."""
         spec = self.params["sources"]
         if spec["dist"] == "uniform":
             if count > self.n:
@@ -86,7 +135,8 @@ class Mix:
                                  f"{self.n} vertices")
             return r.choice(self.n, size=count, replace=False)
         if spec["dist"] == "zipf":
-            perm = r.permutation(self.n)
+            if perm is None:
+                perm = r.permutation(self.n)
             return perm[zipf_ranks(r, self.n, count, spec["constant"])]
         raise ValueError(f"unknown source distribution {spec['dist']!r}")
 

@@ -23,6 +23,12 @@ from bench.registry import ROOT, Benchmark
 
 PEAKS_FILE = Path(__file__).resolve().parent / "peaks.json"
 TRACE_MAX_S = 5.0          # longest profiler window
+STEP_SPAN = "repro.scheduler.step_wave"   # the program's span of one wave
+# the stages inside it, each per wave but finalize, which runs per answer
+WAVE_STAGES = ("repro.scheduler.admit", "repro.wave.prepare",
+               "repro.wave.launch", "repro.wave.device_wait", "repro.wave.d2h",
+               "repro.scheduler.accumulate")
+FINALIZE_SPAN = "repro.scheduler.finalize"
 
 
 class NoChip(RuntimeError):
@@ -58,6 +64,26 @@ def enable_compile_cache(bench: Benchmark) -> str:
     return path
 
 
+@contextlib.contextmanager
+def stage_spans(on: bool):
+    """The program's own stage spans (``repro.telemetry``), counted from
+    zero and on from before set-up in a traced run, where the program has
+    them; off again when the run ends."""
+    try:
+        from repro import telemetry
+    except ImportError:
+        telemetry = None
+    if not on or telemetry is None:
+        yield
+        return
+    telemetry.reset()
+    telemetry.enable(True)
+    try:
+        yield
+    finally:
+        telemetry.enable(False)
+
+
 class Phases:
     """Wall time of each set-up phase, and the compiles inside it."""
 
@@ -87,8 +113,10 @@ class Counters:
         self.at_stop = (time.perf_counter(), self.read())
 
     def delta(self) -> Dict[str, float]:
+        """Each counter's move over the window; one first seen at the close
+        (a stage span that had not yet run) moved from 0."""
         a, b = self.at_start[1], self.at_stop[1]
-        return {k: b[k] - a[k] for k in a}
+        return {k: b[k] - a.get(k, 0) for k in b}
 
 
 class TraceWindow:
@@ -130,7 +158,7 @@ class TraceWindow:
         c1 = self.read()
         self.annotation.__exit__(None, None, None)
         self.profile.__exit__(None, None, None)
-        self.counts = {k: c1[k] - self.c0[k] for k in c1}
+        self.counts = {k: c1[k] - self.c0.get(k, 0) for k in c1}
         self.state = "done"
 
     def reduce(self) -> Optional[dict]:
@@ -164,7 +192,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
         f"({mix['loop']} loop), seed {seed}, window {seconds} s, "
         f"trace {int(trace)}, device {dev.platform} {dev.device_kind} "
         f"x{len(devices)}")
-    with tracing.CompileCounter() as cc:
+    with tracing.CompileCounter() as cc, stage_spans(trace):
         phases = Phases(cc)
         with phases("graph (load, or generate once per checkout)"):
             n, row_ptr, col_idx, made = store.base_graph(
@@ -207,6 +235,7 @@ def _serve(cfg, mix, n, graph, seed, seconds, spans, phases, trace,
         gw = Gateway.open(g, rc, replicas=1)
         jax.block_until_ready(gw.pool.index.endpoints)
     index_build_s = time.perf_counter() - t_build
+    setup_spans = gw.stats().get("spans")
     svc = gw.pool.replicas[0]
     sched = svc.scheduler
     if mix["loop"] == "open":
@@ -216,11 +245,17 @@ def _serve(cfg, mix, n, graph, seed, seconds, spans, phases, trace,
     def read():
         st = svc.serving_stats()
         gs = gw.stats()
-        return {"waves_run": st.waves_run,
-                "walks_executed": st.walks_executed,
-                "requests": gs["requests"], "cache_hits": gs["cache_hits"],
-                "joins": gs["joins"], "sheds": gs["sheds"],
-                "rejected": gs["rejected"]}
+        out = {"waves_run": st.waves_run, "finished": st.finished,
+               "walks_executed": st.walks_executed,
+               "requests": gs["requests"], "cache_hits": gs["cache_hits"],
+               "joins": gs["joins"], "sheds": gs["sheds"],
+               "rejected": gs["rejected"]}
+        if hasattr(st, "tally_bytes_out"):
+            out["tally_bytes_out"] = st.tally_bytes_out
+        for name, totals in (gs.get("spans") or {}).items():
+            for field in ("n", "s", "self_s"):
+                out[f"{name}.{field}"] = totals[field]
+        return out
 
     counters = Counters(read)
     tw = TraceWindow(trace, trace_dir, seconds, read)
@@ -277,7 +312,7 @@ def _serve(cfg, mix, n, graph, seed, seconds, spans, phases, trace,
         "answer_p90_s": stats.percentile(lat, 90) if lat else None,
         "counters": delta, "max_walks": max_walks,
         "waves_host": waves_host, "wave_s": wave_s,
-        "index_build_s": index_build_s,
+        "index_build_s": index_build_s, "setup_spans": setup_spans,
         "trace": tw.reduce(), "trace_counts": tw.counts,
     }
     return {"attempted": len(recs), "failed": refused + failed + unanswered,
@@ -355,11 +390,32 @@ def _result(bench: Benchmark, cell_name: str, out: dict, trace: bool,
 
 def _trace_checks(run_info: dict) -> None:
     """The traced run's own consistency: device busy time within the
-    window, and as many wave (or estimate) programs in the trace as the
-    program's counters moved while it ran."""
+    window, and as many wave (or estimate) programs, and as many
+    ``repro.scheduler.step_wave`` spans, in the trace as the program's
+    counters moved while it ran. Logs where the device's idle time went."""
     red, counts = run_info["trace"], run_info["trace_counts"] or {}
     log(f"trace: busy {red['busy_s']:.6f} s of a {red['window_s']:.6f} s "
         f"window ({'ok' if red['busy_s'] <= red['window_s'] else 'OVER'})")
+    idle = red["window_s"] - red["busy_s"]
+    for name, sec in list(red["idle_by_span"].items())[:10]:
+        log(f"trace: idle {sec:.6f} s ({sec / idle:.2%} of the idle time) "
+            f"under {name}")
+    if f"{STEP_SPAN}.n" in counts:
+        traced = red["span_counts"].get(STEP_SPAN, 0)
+        log(f"trace: {traced} {STEP_SPAN} spans in the trace, counters "
+            f"moved {counts['waves_run']} "
+            f"({'ok' if traced == counts['waves_run'] else 'differ'})")
+    c = run_info.get("counters") or {}
+    if c.get(f"{STEP_SPAN}.s") and c.get(f"{FINALIZE_SPAN}.n"):
+        waves = c["waves_run"]
+        answers = c["finished"] / waves
+        stages = (sum(c.get(f"{s}.s", 0.0) for s in WAVE_STAGES) / waves
+                  + c[f"{FINALIZE_SPAN}.s"] / c[f"{FINALIZE_SPAN}.n"]
+                  * answers)
+        step = c[f"{STEP_SPAN}.s"] / waves
+        log(f"stages: {stages * 1e3:.4f} ms of {step * 1e3:.4f} ms per wave "
+            f"in {STEP_SPAN} ({stages / step:.2%}), finalize at "
+            f"{answers:.4f} answers per wave")
     for name, c in sorted(red["modules"].items(), key=lambda x: -x[1])[:6]:
         log(f"trace: program {name} ran {c:g} times")
     key = "waves_run" if "waves_run" in counts else "estimates"

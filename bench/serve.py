@@ -105,12 +105,13 @@ class Client:
 
 
 def closed_loop(gw, mix: Mix, window_s: float, spans, counters, tick):
-    """``outstanding`` requests kept in flight, warm-up and window alike.
-    Returns the records of the window (those sent in it, and those sent
-    before it that it answered), its ``(t0, t_end)`` (``window_s`` or a
-    little more) and the client."""
+    """``outstanding`` requests kept in flight, warm-up and window alike,
+    drawn from the mix's endless supply (``Mix.stream``). Returns the
+    records of the window (those sent in it, and those sent before it that
+    it answered), its ``(t0, t_end)`` (``window_s`` or a little more) and
+    the client."""
     outstanding = int(mix.params["outstanding"])
-    stream = iter(mix.requests(int(mix.params["max_requests"])))
+    stream = mix.stream()
     client = Client(gw, spans)
 
     def refill(keep: List[Rec]):

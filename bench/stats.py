@@ -29,3 +29,21 @@ def occupancy(walks_executed: int, waves: int, max_walks: int
     if waves <= 0:
         return None
     return walks_executed / (waves * max_walks)
+
+
+def span_ms(run: dict, span: str, per: str = "waves_run") -> Optional[float]:
+    """Milliseconds of the program's stage span ``span`` over the window
+    (its totals differenced), per unit of the window counter ``per``
+    (``waves_run``, or ``<span>.n`` for each call). None where the spans
+    were off, as in an untraced run, or nothing ran."""
+    c = run.get("counters") or {}
+    if f"{span}.s" not in c or not c.get(per):
+        return None
+    return 1e3 * c[f"{span}.s"] / c[per]
+
+
+def setup_span_s(run: dict, span: str) -> Optional[float]:
+    """Seconds of the program's stage span ``span`` in set-up, up to the
+    end of ``Gateway.open``; None where the spans were off."""
+    totals = (run.get("setup_spans") or {}).get(span)
+    return None if totals is None else totals["s"]

@@ -10,6 +10,6 @@ SEED = 2**31 + 4242
 WINDOW_S = 1.5
 
 
-def run(root, cell: str, control=None) -> dict:
-    return harness.run(cell, SEED, WINDOW_S, False, time.time(), root=root,
+def run(root, cell: str, control=None, trace=False) -> dict:
+    return harness.run(cell, SEED, WINDOW_S, trace, time.time(), root=root,
                        require_tpu=False, cache=False, control=control)

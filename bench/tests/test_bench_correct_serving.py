@@ -7,7 +7,9 @@ import pytest
 
 from repro.query import scheduler as sched_mod
 
+from bench import tracing
 from bench.tests import faults, tiny
+from bench.tests.test_bench_arithmetic import STAGES
 
 
 @pytest.fixture(scope="module")
@@ -69,3 +71,33 @@ def test_open_loop_cell_is_correct_and_reports_its_latency(root):
     r = faults.run(root, "lj-ppr-interactive")
     assert r["correct"], r["checks"]
     assert r["metrics"]["answer_p90_s"]["value"] > 0
+
+
+def test_a_traced_run_reports_every_stage_of_the_wave(root, monkeypatch,
+                                                      capsys):
+    """A traced run turns the program's stage spans on and reads each
+    stage metric; the trace holds one ``repro.scheduler.step_wave`` span
+    per wave the counters saw. A CPU trace has no device plane, so one
+    device op per wave, over its ``repro.wave.device_wait`` span, stands
+    in for the chip's."""
+    load = tracing.load_events
+
+    def with_a_device(xplane):
+        events = load(xplane)
+        return events + [
+            ("/device:TPU:0", line, name, t, d)
+            for _, _, span, t, d in events if span == "repro.wave.device_wait"
+            for line, name in (("XLA Modules", "jit_wave(0)"),
+                               ("XLA Ops", "fusion.0"))]
+
+    monkeypatch.setattr(tracing, "load_events", with_a_device)
+    r = faults.run(root, "lj-ppr-refresh", trace=True)
+    out = capsys.readouterr().out
+    assert r["correct"], r["checks"]
+    for name in STAGES:
+        assert r["metrics"][name]["value"] > 0, name
+    assert r["metrics"]["wave.tally_mb"]["value"] == 4 * 3000 * 4 / 1e6
+    line = next(x for x in out.splitlines()
+                if "repro.scheduler.step_wave spans in the trace" in x)
+    assert line.endswith("(ok)"), line
+    assert "of the idle time) under repro." in out

@@ -93,3 +93,60 @@ def test_recorded_tpu_trace():
     assert red["device_ops"][0][0] == "while.10"
     assert all(name.startswith("bench.") for name, _ in red["idle_gaps"])
     assert sum(s for _, s in red["idle_gaps"]) <= red["window_s"]
+
+
+def test_recorded_tpu_trace_reduces_as_it_always_has():
+    """The fields of the reduction before the program's spans were read
+    (``reduced.json``: the same trace reduced then) are unchanged, and
+    the idle time splits whole among the host spans."""
+    import json
+    red = tracing.reduce(tracing.read_events(RECORDED))
+    before = json.loads(RECORDED.with_suffix(".reduced.json").read_text())
+    for key in ("busy_s", "window_s", "device_ops", "idle_gaps", "modules"):
+        assert red[key] == before[key], key
+    assert sum(red["idle_by_span"].values()) == pytest.approx(
+        red["window_s"] - red["busy_s"], rel=1e-12)
+    assert set(red["idle_by_span"]) <= {"bench.step", "bench.poll",
+                                        "bench.submit", "host"}
+    assert red["span_counts"]["bench.step"] == 6
+
+
+# the program's stage spans inside the harness's first step: step_wave
+# 2-38 holds prepare 4-9 and d2h 20-30
+NESTED = EVENTS + [
+    ev(HOST, "python", "repro.scheduler.step_wave", 2, 36),
+    ev(HOST, "python", "repro.wave.prepare", 4, 5),
+    ev(HOST, "python", "repro.wave.d2h", 20, 10),
+]
+
+
+def test_idle_time_goes_to_the_innermost_host_span():
+    red = tracing.reduce(NESTED)
+    # idle 0-10 (step 0-2, step_wave 2-4 and 9-10, prepare 4-9),
+    # 20-70 (d2h 20-30, step_wave 30-38, step 38-40, poll 40-60,
+    # step 60-70), 75-100 (step)
+    want = {"bench.step": 0.002 + 0.002 + 0.010 + 0.025,
+            "repro.scheduler.step_wave": 0.002 + 0.001 + 0.008,
+            "repro.wave.prepare": 0.005, "repro.wave.d2h": 0.010,
+            "bench.poll": 0.020}
+    assert red["idle_by_span"] == pytest.approx(want)
+    assert sum(red["idle_by_span"].values()) == pytest.approx(
+        red["window_s"] - red["busy_s"])
+    assert red["span_counts"] == {
+        "bench.step": 2, "bench.poll": 1, "repro.scheduler.step_wave": 1,
+        "repro.wave.prepare": 1, "repro.wave.d2h": 1}
+
+
+def test_idle_gaps_are_named_by_the_harness_spans_alone():
+    with_program = tracing.reduce(NESTED)
+    without = tracing.reduce(EVENTS)
+    for key in ("busy_s", "window_s", "device_ops", "idle_gaps", "modules"):
+        assert with_program[key] == without[key], key
+
+
+def test_idle_outside_every_span_is_the_hosts():
+    events = [ev(HOST, "python", "bench.trace_window", 0, 10),
+              ev(DEV, "XLA Ops", "gather", 2, 2)]
+    red = tracing.reduce(events)
+    assert red["idle_by_span"] == pytest.approx({"host": 0.008})
+    assert red["span_counts"] == {}

@@ -6,7 +6,9 @@ The harness wraps each of its own calls into the program in a span
 ``bench.estimate``): a ``jax.profiler.TraceAnnotation``, so it lands in the
 trace on the device's clock, and a host-clock interval kept in memory.
 The traced part of a run sits inside a ``bench.trace_window`` span; the
-reduction measures the device against that span.
+reduction measures the device against that span. The program's own stage
+spans (``repro.*``, on in a traced run) are kept too: they split the
+device's idle time by what the host was doing inside the harness's calls.
 """
 from __future__ import annotations
 
@@ -25,6 +27,7 @@ DEVICE_PLANE_PREFIX = "/device:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 SPAN_PREFIX = "bench."
+PROGRAM_SPAN_PREFIX = "repro."
 # The program whose runs a counter of the harness counts, by the name the
 # trace gives its executable.
 PROGRAMS = {"waves_run": "jit_wave", "estimates": "jit__walks_program"}
@@ -111,7 +114,7 @@ Event = Tuple[str, str, str, float, float]   # plane, line, name, t0, dur (ns)
 
 def load_events(xplane: Path) -> List[Event]:
     """The events the reduction reads: the device planes' op and module
-    lines, and the harness's own host spans."""
+    lines, and the host spans of the harness and of the program."""
     pd = jax.profiler.ProfileData.from_file(str(xplane))
     out: List[Event] = []
     for plane in pd.planes:
@@ -120,7 +123,8 @@ def load_events(xplane: Path) -> List[Event]:
             if device and line.name in (OPS_LINE, MODULES_LINE):
                 keep = lambda ev: True
             elif not device:
-                keep = lambda ev: ev.name.startswith(SPAN_PREFIX)
+                keep = lambda ev: ev.name.startswith(
+                    (SPAN_PREFIX, PROGRAM_SPAN_PREFIX))
             else:
                 continue
             for ev in line.events:
@@ -145,9 +149,12 @@ def _union(intervals: Iterable[Tuple[float, float]]) -> List[List[float]]:
 def reduce(events: Sequence[Event], top: int = 10) -> Optional[dict]:
     """Device busy and idle time over the ``bench.trace_window`` span, the
     device operations that took most time, the longest idle gaps named by
-    the host span that covers most of each, and how often each program ran.
-    Times in seconds, averaged over the device planes. None where the trace
-    holds no window or no device."""
+    the harness span (``bench.*``) that covers most of each, how often each
+    program ran, the idle time split by the innermost host span at each
+    instant (``idle_by_span``: a ``repro.*`` span, else a ``bench.*`` one,
+    else ``host``), and how many host spans of each name started in the
+    window (``span_counts``). Times in seconds, averaged over the device
+    planes. None where the trace holds no window or no device."""
     windows = [(t, t + d) for p, l, name, t, d in events
                if name == WINDOW_SPAN]
     devices = sorted({p for p, l, *_ in events
@@ -155,10 +162,12 @@ def reduce(events: Sequence[Event], top: int = 10) -> Optional[dict]:
     if not windows or not devices:
         return None
     w0, w1 = windows[0]
-    spans = [(name, t, t + d) for p, l, name, t, d in events
-             if not p.startswith(DEVICE_PLANE_PREFIX) and name != WINDOW_SPAN]
+    host = [(name, t, t + d) for p, l, name, t, d in events
+            if not p.startswith(DEVICE_PLANE_PREFIX) and name != WINDOW_SPAN]
+    spans = [s for s in host if s[0].startswith(SPAN_PREFIX)]
+    inside = [s for s in host if s[1] < w1 and s[2] > w0]
     busy_total, ops, gaps = 0.0, collections.Counter(), []
-    modules = collections.Counter()
+    modules, idle_by = collections.Counter(), collections.Counter()
     for dev in devices:
         ivs = [(max(t, w0), min(t + d, w1)) for p, l, name, t, d in events
                if p == dev and l == OPS_LINE and t < w1 and t + d > w0]
@@ -172,9 +181,10 @@ def reduce(events: Sequence[Event], top: int = 10) -> Optional[dict]:
         merged = _union(ivs)
         busy_total += sum(b - a for a, b in merged)
         edges = [w0] + [x for iv in merged for x in iv] + [w1]
-        for a, b in zip(edges[::2], edges[1::2]):
-            if b > a:
-                gaps.append((_cover(spans, a, b), (b - a) / 1e9))
+        idle = [(a, b) for a, b in zip(edges[::2], edges[1::2]) if b > a]
+        for a, b in idle:
+            gaps.append((_cover(spans, a, b), (b - a) / 1e9))
+        idle_by.update(_idle_by_span(inside, idle))
     nd = len(devices)
     gaps.sort(key=lambda g: -g[1])
     return {
@@ -184,6 +194,10 @@ def reduce(events: Sequence[Event], top: int = 10) -> Optional[dict]:
                        for name, ns in ops.most_common(top)],
         "idle_gaps": [[name, s] for name, s in gaps[:top]],
         "modules": {name: c / nd for name, c in modules.items()},
+        "idle_by_span": {name: ns / nd / 1e9
+                         for name, ns in idle_by.most_common()},
+        "span_counts": dict(collections.Counter(
+            name for name, t0, _ in host if w0 <= t0 < w1)),
     }
 
 
@@ -209,6 +223,37 @@ def _cover(spans, a: float, b: float) -> str:
         if o > best:
             best, name = o, sname
     return name
+
+
+def _idle_by_span(spans, idle) -> collections.Counter:
+    """Nanoseconds of the sorted, disjoint ``idle`` intervals under each
+    host span, each instant given to the innermost span open then."""
+    out = collections.Counter()
+    cuts = sorted({x for _, a, b in spans for x in (a, b)}
+                  | {x for iv in idle for x in iv})
+    opening = sorted(spans, key=lambda s: s[1])
+    active: List[tuple] = []
+    i = j = 0
+    for a, b in zip(cuts, cuts[1:]):
+        while i < len(opening) and opening[i][1] <= a:
+            active.append(opening[i])
+            i += 1
+        active = [s for s in active if s[2] > a]
+        while j < len(idle) and idle[j][1] <= a:
+            j += 1
+        if j < len(idle) and idle[j][0] <= a:
+            out[_innermost(active)] += b - a
+    return out
+
+
+def _innermost(active) -> str:
+    """The span opened last among the program's open spans, else among the
+    harness's, else ``host``."""
+    for prefix in (PROGRAM_SPAN_PREFIX, SPAN_PREFIX):
+        mine = [s for s in active if s[0].startswith(prefix)]
+        if mine:
+            return max(mine, key=lambda s: (s[1], -s[2]))[0]
+    return "host"
 
 
 def save_events(events: Sequence[Event], path: Path) -> None:
