@@ -327,7 +327,7 @@ def serve_loop_oracle(g, index, cfg: RuntimeConfig, batches) -> list:
     sched = QueryScheduler(
         g, shard_walk_index(index, 4), max_walks=scfg.max_walks,
         max_queries=scfg.max_queries, max_steps=scfg.max_steps,
-        p_T=cfg.p_T, impl="ref", tally_impl="ref", seed=cfg.runtime.seed,
+        p_T=cfg.p_T, impl="ref", seed=cfg.runtime.seed,
         runtime=ShardRuntime(num_shards=4, mesh=None),
         sharded_dispatch="loop")
     check(sched.dispatch == "loop", f"oracle ran {sched.dispatch}")
@@ -350,7 +350,7 @@ def serve_fused(g, index: ShardedWalkIndex, cfg: RuntimeConfig,
     sched = QueryScheduler(
         g, index, max_walks=scfg.max_walks, max_queries=scfg.max_queries,
         max_steps=scfg.max_steps, p_T=cfg.p_T, impl=cfg.kernel.stitch_impl,
-        tally_impl=cfg.kernel.tally_impl, seed=cfg.runtime.seed,
+        seed=cfg.runtime.seed,
         runtime=ShardRuntime(num_shards=index.num_shards, mesh=None))
     check(sched.dispatch == "fused", f"reference ran {sched.dispatch}")
     for req in requests:

@@ -53,13 +53,12 @@ class KernelConfig:
 
     ``draw`` picks the blocking-walk scatter draw, ``step_impl`` the plain
     (p_s = 1) walker-step backend, ``stitch_impl`` the serving wave's
-    stitch-round backend, ``tally_impl`` the endpoint histogram.
+    stitch-round backend.
     """
 
     draw: str = "auto"          # auto | rejection | cumsum
     step_impl: str = "xla"      # xla | pallas | stream | auto | ref
     stitch_impl: str = "xla"    # xla | pallas | ref
-    tally_impl: str = "ref"     # ref | sort | pallas | auto
 
 
 @dataclasses.dataclass(frozen=True)

@@ -207,19 +207,22 @@ class ShardRuntime:
         num_replicated: int = 0,
         num_outputs: int = 1,
         check_vma: bool = True,
+        replicated_out: bool = False,
     ) -> Callable:
         """Wraps a per-shard body as one ``shard_map`` over the mesh
         (unjitted — the dry-run path wants to control ``in_shardings``).
 
         The body sees its first ``num_sharded`` arguments as ``[1, ...]``
         per-shard blocks and the rest replicated; every output is a
-        ``[1, ...]`` per-shard block (``P(axis)``). ``check_vma=False`` is
-        for bodies that lower through ``pallas_call`` (jax has no
-        replication rule for it).
+        ``[1, ...]`` per-shard block (``P(axis)``), or, with
+        ``replicated_out``, a value every device holds alike (``P()``).
+        ``check_vma=False`` is for bodies that lower through ``pallas_call``
+        (jax has no replication rule for it).
         """
         ax = self.axis_name
         in_specs = (P(ax),) * num_sharded + (P(),) * num_replicated
-        out_specs = P(ax) if num_outputs == 1 else (P(ax),) * num_outputs
+        out = P() if replicated_out else P(ax)
+        out_specs = out if num_outputs == 1 else (out,) * num_outputs
         kwargs = {} if check_vma else {"check_vma": False}
         return jax.shard_map(body, mesh=self.require_mesh(),
                              in_specs=in_specs, out_specs=out_specs,
@@ -228,7 +231,8 @@ class ShardRuntime:
     def sharded_call(self, body: Callable, num_sharded: int,
                      num_replicated: int = 0, num_outputs: int = 1,
                      check_vma: bool = True,
-                     donate_argnums: Sequence[int] = ()) -> Callable:
+                     donate_argnums: Sequence[int] = (),
+                     replicated_out: bool = False) -> Callable:
         """Jitted :meth:`shard_map_fn` — the common execution entry.
 
         ``donate_argnums`` forwards to ``jax.jit``: callers donate operands
@@ -237,7 +241,8 @@ class ShardRuntime:
         allocating fresh ones every dispatch."""
         return jax.jit(self.shard_map_fn(
             body, num_sharded, num_replicated, num_outputs,
-            check_vma=check_vma), donate_argnums=tuple(donate_argnums))
+            check_vma=check_vma, replicated_out=replicated_out),
+            donate_argnums=tuple(donate_argnums))
 
     def map_shards(self, program: Callable, *args, **kwargs) -> list:
         """Single-device dispatch: runs ``program(shard_id, *args)`` for

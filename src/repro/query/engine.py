@@ -46,8 +46,8 @@ from repro.query.index import WalkIndex
 
 # Donating the walk-state operands lets XLA write wave outputs into the
 # dead input buffers; when a buffer's shape/layout doesn't match any output
-# jax emits a UserWarning per compile. That mismatch is expected here (the
-# tally output is [Q+1, n], the donated operands are [W]) and harmless —
+# jax emits a UserWarning per compile. That mismatch is expected here (one
+# int32[W] output, five donated operands, some bool) and harmless —
 # silence exactly that message, nothing else.
 warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable")
@@ -199,7 +199,7 @@ class WaveSpec:
     specs share one executable.
     """
 
-    n: int               # graph vertices (tally bins per query row)
+    n: int               # graph vertices
     R: int               # segments per vertex
     L: int               # segment length
     q_max: int           # stitch rounds (lax.scan length)
@@ -209,7 +209,6 @@ class WaveSpec:
     Q: int               # query-slot bucket
     p_T: float           # geometric stop probability (baked into lengths)
     impl: str            # stitch backend: xla | pallas | ref
-    tally_impl: str      # histogram backend: ref | sort | pallas | auto
     donate: bool         # donate walk-state operands to the executable
 
 
@@ -250,13 +249,17 @@ def wave_prep(
 
 def build_wave_program(spec: WaveSpec):
     """One fused, jitted wave program for ``spec``: prologue + ``lax.scan``
-    over stitch rounds + one final histogram — a single device dispatch
-    where the legacy sharded host loop paid ``S × q_max`` of them.
+    over stitch rounds — a single device dispatch where the legacy sharded
+    host loop paid ``S × q_max`` of them.
 
     Signature of the returned program::
 
         wave(slab_flat, row_ptr, col_idx, deg,
-             start, uniform, qid, t_cap, key_data, lost) -> int32[Q, n]
+             start, uniform, qid, t_cap, key_data, lost) -> int32[W]
+
+    The result is each walk's final vertex, ``-1`` for a walk that is
+    discarded (``qid == Q``: an idle slot) or died on an evicted shard —
+    ``4·W`` bytes, whatever ``n``. The scheduler counts them per query.
 
     ``slab_flat`` is the flat endpoint slab — the dense ``[n, R]`` slab, or
     the sharded index's stacked blocks ``[S·sz, R]`` flattened (row-padded;
@@ -269,7 +272,7 @@ def build_wave_program(spec: WaveSpec):
 
     ``lost`` is the bool[S] eviction mask: a walk that still needs a gather
     while sitting in a lost shard's endpoint range — or whose final vertex
-    lands in one — dies (position frozen, routed to the ``Q`` discard row).
+    lands in one — dies (its endpoint is ``-1``).
     All-False masks leave the program bit-identical to an unmasked one.
 
     With ``spec.donate`` the walk-state operands (start / uniform / qid /
@@ -295,7 +298,7 @@ def build_wave_program(spec: WaveSpec):
                 nxt = jnp.take(slab_flat, pos * R + (s0 + j) % R, axis=0)
             else:
                 # gather-only stitch kernel: the per-round tally is not
-                # computed at all (the wave histograms once, below).
+                # computed at all (the host counts the endpoints).
                 nxt, _ = ops.stitch_step(
                     pos, (q == j).astype(jnp.int32), s0 + j,
                     slab_flat.reshape(-1, R), n, impl=spec.impl,
@@ -308,10 +311,7 @@ def build_wave_program(spec: WaveSpec):
                 round_fn, (pos, alive),
                 jnp.arange(spec.q_max, dtype=jnp.int32))
         alive = alive & ~lost[jnp.clip(pos // sz, 0, S - 1)]
-        qid_eff = jnp.where(alive, qid, Q)   # dead walks → discard bin
-        counts = ops.frog_count(pos + qid_eff * n, (Q + 1) * n,
-                                impl=spec.tally_impl)
-        return counts.reshape(Q + 1, n)[:Q]
+        return jnp.where(alive & (qid < Q), pos, -1)
 
     donate = (4, 5, 6, 7, 9) if spec.donate else ()
     return jax.jit(wave, donate_argnums=donate)

@@ -12,10 +12,12 @@ The device program is one fixed shape — ``max_walks`` walk slots ×
             several waves keeps its slot while finished queries free theirs
             mid-flight;
   execute   one wave program advances all walks (residual steps + index
-            stitching, ``query/engine.py``) and histograms endpoints into
-            per-query-slot bins;
+            stitching, ``query/engine.py``) and hands the host each walk's
+            final vertex; each query slot counts its own walks' endpoints
+            over their support (sorted vertex ids and their counts), so
+            nothing per query is sized by ``n``;
   retire    queries whose walk budget completed finalize top-k from their
-            accumulated counters and release the slot.
+            counts and release the slot.
 
 **Execution dispatch** (the ``distributed/runtime.py`` layer): with a dense
 :class:`~repro.query.index.WalkIndex` the wave is the single-device gathered
@@ -24,12 +26,12 @@ ShardedWalkIndex` the slab is *never reassembled*: on a mesh the wave runs
 as one ``shard_map`` over the runtime's ``"vertex"`` axis — device ``s``
 holds only its ``[shard_size, R]`` slab block, each stitch round routes
 every walk to the shard owning its current vertex by endpoint range
-(masked local gather), per-shard partial results are reduced with ``psum``,
-and the tally lands in shard-local bins (``out_specs=P(axis)``). On a
-single device the identical per-shard program runs as the runtime's host
-loop, one block resident at a time. All three paths draw from the same key
-stream, so with the same slab content they produce byte-identical answers
-(tests assert it).
+(masked local gather), and per-shard partial results are reduced with
+``psum``, so the walk state — and the endpoints the wave returns — stay
+replicated. On a single device the identical per-shard program runs as the
+runtime's host loop, one block resident at a time. All three paths draw
+from the same key stream, so with the same slab content they return
+byte-identical endpoints, hence answers (tests assert it).
 
 **Admission** is deadline- and queue-depth-aware: ``QueryRequest.slo_s``
 declares a latency SLO, and ``submit()`` checks the Theorem-1 ``(t, N)``
@@ -122,32 +124,16 @@ def _topk_stable(scores: np.ndarray, k: int) -> np.ndarray:
     """First ``k`` indices of ``np.argsort(-scores, kind="stable")`` without
     sorting all ``n`` scores.
 
-    This is the per-``poll()`` hot path: every anytime :meth:`partial`
-    snapshot ranks the accumulated stop counts, and the full n-element
-    argsort was the serving-handle overhead. Two strategies:
-
-    * **sparse** — serving count vectors have support bounded by the
-      walks executed (≪ n in the paper's regime), so when every nonzero
-      entry is positive and the support is small, a stable sort of just
-      the support reproduces the full sort's head; entries outside the
-      support are exact zeros, whose tie order under the full stable
-      argsort is ascending index — the pad.
-    * **dense** — ``np.partition`` finds the k-th largest in O(n); the
-      candidate set ``scores >= kth`` is a superset of the stable top-k
-      (it includes every boundary tie), and a stable descending sort of
-      just the candidates reproduces the full sort's relative order.
+    The scheduler ranks a query's counts over their support with it, at
+    every :meth:`QueryScheduler.partial` snapshot and at finalize.
+    ``np.partition`` finds the k-th largest in O(n); the candidate set
+    ``scores >= kth`` is a superset of the stable top-k (it includes every
+    boundary tie), and a stable descending sort of just the candidates
+    reproduces the full sort's relative order.
     """
     n = scores.shape[0]
     if k >= n:
         return np.argsort(-scores, kind="stable")[:k]
-    nz = np.flatnonzero(scores)
-    if nz.size <= n >> 2 and (nz.size == 0 or scores[nz].min() > 0):
-        top = nz[np.argsort(-scores[nz], kind="stable")][:k]
-        if top.size == k:
-            return top
-        pad = np.setdiff1d(np.arange(min(n, k + nz.size)),
-                           nz)[:k - top.size]
-        return np.concatenate([top, pad])
     kth = np.partition(scores, n - k)[n - k]
     cand = np.flatnonzero(scores >= kth)
     return cand[np.argsort(-scores[cand], kind="stable")][:k]
@@ -281,8 +267,8 @@ class SchedulerStats:
     max_walks: int
     max_queries: int
     epoch: int = 0                   # graph epoch this scheduler serves
-    # bytes of wave tally brought to the host, summed over waves; per wave
-    # it is what the dense ``[Q_b, n]`` tally costs the host to take in
+    # bytes of wave result copied to the host, summed over waves: each
+    # wave's int32[W_b] endpoints, 4·W_b bytes whatever n is
     tally_bytes_out: int = 0
 
 
@@ -295,13 +281,16 @@ class _Queued:
     downgraded: bool
 
 
+def _empty() -> np.ndarray:
+    return np.zeros(0, np.int64)
+
+
 @dataclasses.dataclass
 class _Active:
     req: QueryRequest
     plan: QueryPlan
     remaining: int
     total_walks: int
-    counts: np.ndarray               # int64[n] accumulator
     waves: int
     t_submit: float
     deadline: float
@@ -309,6 +298,35 @@ class _Active:
     executed: int = 0                # walks whose tallies have landed
     lost: int = 0                    # allocated walks that died on a lost shard
     shards_lost: Tuple[int, ...] = ()  # evicted shards seen by this query
+    # the endpoint count over its support: sorted vertex ids, their counts
+    # (every vertex outside ``ids`` has count 0)
+    ids: np.ndarray = dataclasses.field(default_factory=_empty)
+    hits: np.ndarray = dataclasses.field(default_factory=_empty)
+
+    def count(self, ends: np.ndarray) -> None:
+        """Adds one wave's landed endpoints (vertex ids ≥ 0) to the count:
+        O(m + w log(m + w)) for a support of m and w endpoints."""
+        new, c = np.unique(ends, return_counts=True)
+        at = np.searchsorted(self.ids, new)
+        seen = at < self.ids.size
+        seen[seen] = self.ids[at[seen]] == new[seen]
+        self.hits[at[seen]] += c[seen]
+        self.ids = np.insert(self.ids, at[~seen], new[~seen])
+        self.hits = np.insert(self.hits, at[~seen], c[~seen])
+
+    def top(self, k: int) -> Tuple[np.ndarray, np.ndarray]:
+        """``(vertices, counts)`` of the first ``k`` (≤ n) entries of
+        ``np.argsort(-dense, kind="stable")`` over the dense count vector:
+        the support ranked by ``_topk_stable`` (ids ascending, so ties keep
+        the dense order), then, if the support is smaller than ``k``, the
+        zeros in ascending id order — the smallest ids outside it."""
+        at = _topk_stable(self.hits, k)
+        vertices, hits = self.ids[at], self.hits[at]
+        if vertices.size < k:
+            pad = np.setdiff1d(np.arange(k), self.ids)[:k - vertices.size]
+            vertices = np.concatenate([vertices, pad])
+            hits = np.concatenate([hits, np.zeros(pad.size, np.int64)])
+        return vertices, hits
 
 
 class QueryScheduler:
@@ -321,7 +339,6 @@ class QueryScheduler:
         max_steps: int = 32,
         p_T: float = 0.15,
         impl: str = "xla",
-        tally_impl: str = "ref",
         seed: int = 0,
         runtime: Optional[ShardRuntime] = None,
         wave_time_estimate_s: Optional[float] = None,
@@ -351,7 +368,6 @@ class QueryScheduler:
         self.max_steps = max_steps
         self.p_T = p_T
         self.impl = impl
-        self.tally_impl = tally_impl
         self.donate_wave_buffers = donate_wave_buffers
         # AOT wave-program ladder: waves run at the smallest bucket shape
         # ≥ the allocation, so the set of compiled programs is fixed up
@@ -374,7 +390,7 @@ class QueryScheduler:
         self._waves_run = 0
         self._walks_allocated = 0    # walk slots handed out across all waves
         self._walks_executed = 0     # walks whose tallies actually landed
-        self._tally_bytes_out = 0    # tally bytes copied device → host
+        self._tally_bytes_out = 0    # endpoint bytes copied device → host
         # --- fault-tolerance state (PR 6) ---
         self._injector = fault_injector
         self.wave_timeout_s = wave_timeout_s
@@ -469,16 +485,14 @@ class QueryScheduler:
             n=self.g.n, R=self.index.segments_per_vertex,
             L=self.index.segment_len, q_max=self._q_max,
             S=self._S, sz=self._sz, W=W_b, Q=Q_b, p_T=self.p_T,
-            impl=self.impl, tally_impl=self.tally_impl,
-            donate=self.donate_wave_buffers)
+            impl=self.impl, donate=self.donate_wave_buffers)
 
     def _wave_for(self, W_b: int, Q_b: int):
         """The wave callable for one ladder bucket, built on first use and
         cached — ``wave(start, uniform, qid, t_cap, key, lost)`` with every
-        operand at bucket shape. It returns the tally as the program left
-        it: a device ``int32[Q_b, n]`` (fused), a device ``[S, Q_b, sz]``
-        (mesh), or the host loop's stacked ``[S, Q_b, sz]``;
-        :meth:`_tally_to_host` brings it to the host in vertex order."""
+        operand at bucket shape. Every dispatch path returns the walks'
+        endpoints as a device ``int32[W_b]`` (``-1`` for a discarded or dead
+        walk); :meth:`_tally_to_host` brings them to the host."""
         fn = self._wave_fns.get((W_b, Q_b))
         if fn is None:
             if self.dispatch == "mesh":
@@ -511,8 +525,8 @@ class QueryScheduler:
 
     def _build_fused_wave(self, W_b: int, Q_b: int):
         """The fused single-dispatch wave (gathered and sharded host-side
-        serving): prologue + ``lax.scan`` over stitch rounds + one
-        histogram, compiled once per :class:`WaveSpec` in the process-wide
+        serving): prologue + ``lax.scan`` over stitch rounds, compiled once
+        per :class:`WaveSpec` in the process-wide
         :meth:`ShardRuntime.wave_cache` — replicas over the same slab
         geometry share the executable (slab and graph arrays are
         operands, not closures)."""
@@ -543,26 +557,12 @@ class QueryScheduler:
             nxt = jnp.take(block_flat, li * R + slot, axis=0)
             return jnp.where(mine & (j < q), nxt, 0)
         # gather-only local-index stitch kernel ("pallas" | "ref"): the
-        # wave tallies once over final positions, so the per-round tally
-        # is not computed at all (tally=False).
+        # host counts the final positions, so the per-round tally is not
+        # computed at all (tally=False).
         nxt, _ = ops.stitch_step_local(
             pos, (q == j).astype(jnp.int32), s0 + j,
             block_flat.reshape(sz, R), base, impl=self.impl, tally=False)
         return jnp.where(j < q, nxt, 0)
-
-    def _shard_tally(self, pos, qid, base, Q):
-        """Shard-local per-query-slot histogram: walks whose final vertex
-        this shard owns land in its ``[Q, shard_size]`` bins; the rest
-        (other shards' walks + idle slots via ``qid == Q``) are discarded.
-        ``Q`` is the wave's *query-slot bucket* (row count), not
-        ``max_queries`` — ladder waves tally at bucket shape."""
-        sz = self.index.shard_size
-        local = pos - base
-        mine = (local >= 0) & (local < sz)
-        bins = jnp.where(mine, qid * sz + jnp.clip(local, 0, sz - 1),
-                         (Q + 1) * sz)
-        counts = ops.frog_count(bins, (Q + 1) * sz + 1, impl=self.tally_impl)
-        return counts[: (Q + 1) * sz].reshape(Q + 1, sz)[:Q]
 
     def _stitch_rounds(self, pos, q, round_fn, lost_of=None):
         """Applies ``q_max`` stitch rounds where ``round_fn(pos, j)`` sums
@@ -574,8 +574,8 @@ class QueryScheduler:
         ``lost_of(pos) -> bool[W]`` marks walks sitting in an evicted
         shard's endpoint range. A walk that still needs a gather from a
         lost range (``j < q``) — or whose *final* vertex lands in one —
-        dies: ``alive`` goes False and its position freezes, so the tally
-        can route it to the discard bin. With no evictions the mask is
+        dies: ``alive`` goes False and its position freezes, so the wave
+        can return ``-1`` for it. With no evictions the mask is
         all-False and the emitted program is bit-identical to the unmasked
         one. Returns ``(pos, alive)`` (``alive is None`` without a mask).
         """
@@ -595,12 +595,13 @@ class QueryScheduler:
     def _build_mesh_wave(self, W_b: int, Q_b: int):
         """Sharded wave: one ``shard_map`` over the runtime's vertex axis.
 
-        Device ``s`` holds only slab block ``s`` (``in_specs=P(axis)``) and
-        its ``[Q, shard_size]`` tally rows (``out_specs=P(axis)``); walk
-        state is replicated and advanced identically on every device, with
-        the per-round gather contribution reduced by ``psum`` inside one
-        ``lax.scan`` over the stitch rounds — one dispatch per wave, same
-        as the fused single-device program. Walk-state operands are
+        Device ``s`` holds only slab block ``s`` (``in_specs=P(axis)``);
+        walk state is replicated and advanced identically on every device,
+        with the per-round gather contribution reduced by ``psum`` inside
+        one ``lax.scan`` over the stitch rounds — one dispatch per wave,
+        same as the fused single-device program. The endpoints are
+        replicated too, so the output is too (``out_specs=P()``): the host
+        copies one device's ``int32[W]``. Walk-state operands are
         donated. Mesh programs close over the (unhashable) mesh, so they
         cache per-scheduler in ``_wave_fns``, not in the process-wide
         ladder cache.
@@ -642,8 +643,7 @@ class QueryScheduler:
                     round_fn, (pos, alive),
                     jnp.arange(self._q_max, dtype=jnp.int32))
             alive = alive & ~lost[jnp.clip(pos // sz, 0, S - 1)]
-            qid_eff = jnp.where(alive, qid, Q)   # dead walks → discard bin
-            return self._shard_tally(pos, qid_eff, base, Q)[None]
+            return jnp.where(alive & (qid < Q), pos, -1)
 
         # check_vma=False: the fused stitch backends lower through
         # pallas_call (no replication rule), and the body mixes replicated
@@ -652,12 +652,13 @@ class QueryScheduler:
         # and key_data.
         donate = (4, 5, 6, 7, 9) if self.donate_wave_buffers else ()
         fn = rt.sharded_call(body, num_sharded=1, num_replicated=9,
-                             check_vma=False, donate_argnums=donate)
+                             check_vma=False, donate_argnums=donate,
+                             replicated_out=True)
         blocks, csr = self._placed_blocks, self._placed_csr
 
         def wave(start, uniform, qid, t_cap, key, lost):
             return fn(blocks, *csr, start, uniform, qid, t_cap,
-                      ShardRuntime.key_data(key), lost)    # [S, Q, sz]
+                      ShardRuntime.key_data(key), lost)
 
         return wave
 
@@ -678,7 +679,6 @@ class QueryScheduler:
         prep = jax.jit(functools.partial(
             wave_prep, n=g.n, L=index.segment_len, p_T=self.p_T))
         round_s = jax.jit(self._shard_round)
-        tally_s = jax.jit(self._shard_tally, static_argnums=3)
         blocks = [jnp.asarray(index.blocks[s].reshape(-1))
                   for s in range(rt.num_shards)]
 
@@ -699,11 +699,7 @@ class QueryScheduler:
 
             lost_of = lambda p: lost[jnp.clip(p // sz, 0, S - 1)]
             pos, alive = self._stitch_rounds(pos, q, round_fn, lost_of)
-            qid_eff = jnp.where(alive, qid, Q)   # dead walks → discard bin
-            return np.stack([
-                np.zeros((Q, sz), np.int32) if lost_host[s]
-                else np.asarray(tally_s(pos, qid_eff, jnp.int32(s * sz), Q))
-                for s in range(S)])                          # [S, Q, sz]
+            return jnp.where(alive & (qid < Q), pos, -1)
 
         return wave
 
@@ -820,9 +816,8 @@ class QueryScheduler:
             e = self.queue.pop(0)
             self.active[free.pop(0)] = _Active(
                 req=e.req, plan=e.plan, remaining=e.walks,
-                total_walks=e.walks, counts=np.zeros(self.g.n, np.int64),
-                waves=0, t_submit=e.req.t_submit, deadline=e.deadline,
-                downgraded=e.downgraded,
+                total_walks=e.walks, waves=0, t_submit=e.req.t_submit,
+                deadline=e.deadline, downgraded=e.downgraded,
             )
 
     def _edf_order(self) -> List[int]:
@@ -855,8 +850,9 @@ class QueryScheduler:
 
         The wave runs at the smallest ladder bucket that fits the
         allocation — walk slots padded to ``W_b``, query slots *compacted*
-        (EDF allocation order) into ``[0, Q_b)`` rows and scattered back to
-        their slots on the host. Bucket choice is a pure function of
+        (EDF allocation order) into query ids ``[0, Q_b)``, each slot's
+        walks one contiguous slice, which its endpoints come back in.
+        Bucket choice is a pure function of
         host-side scheduler state, so every dispatch path and replica picks
         the same bucket — the cross-path byte-identity contract holds
         bucket by bucket.
@@ -881,10 +877,10 @@ class QueryScheduler:
             Q_b = self._bucket(self._query_ladder, len(alloc))
             start = np.zeros(W_b, np.int32)
             uniform = np.zeros(W_b, bool)
-            qid = np.full(W_b, Q_b, np.int32)    # default: discard bin
+            qid = np.full(W_b, Q_b, np.int32)    # default: idle, returns -1
             t_cap = np.zeros(W_b, np.int32)
             cursor = 0
-            # ``alloc`` preserves EDF order, so compact row ci is
+            # ``alloc`` preserves EDF order, so compact query id ci is
             # deterministic from (deadlines, slots) alone — identical
             # across dispatch paths.
             for ci, (s, w) in enumerate(alloc.items()):
@@ -898,8 +894,8 @@ class QueryScheduler:
                     uniform[sl] = True
                 cursor += w
             self._key, k_wave = jax.random.split(self._key)
-        counts, clean, dt = self._run_wave(start, uniform, qid, t_cap,
-                                           k_wave, W_b, Q_b)
+        ends, clean, dt = self._run_wave(start, uniform, qid, t_cap,
+                                         k_wave, W_b, Q_b)
         now = time.perf_counter()
         self._walks_allocated += sum(alloc.values())
         # EMA of measured wave time — feeds the admission budget check. The
@@ -918,16 +914,19 @@ class QueryScheduler:
 
         done = []
         with telemetry.span("repro.scheduler.accumulate"):
-            for ci, (s, w) in enumerate(alloc.items()):
+            ends = np.asarray(ends)          # a device array is taken too
+            cursor = 0
+            for s, w in alloc.items():
+                # the slot's walks are the contiguous slice the operand
+                # build gave it; a discarded or dead walk's endpoint is -1
+                mine = ends[cursor:cursor + w]
+                cursor += w
                 if s not in self.active:     # evicted mid-wave? impossible
                     continue                 # today, but stay defensive
                 a = self.active[s]
-                row = counts[ci]             # compact row → query slot
-                # every surviving walk lands in exactly one tally bin, so
-                # the slot's landed count is the row sum — lost walks need
-                # no extra program output.
-                landed = int(row.sum())
-                a.counts += row
+                mine = mine[mine >= 0]
+                landed = mine.size
+                a.count(mine)
                 a.remaining -= w
                 a.executed += landed
                 self._walks_executed += landed
@@ -942,7 +941,7 @@ class QueryScheduler:
                 if a.remaining == 0 or early:
                     done.append((s, early))
         for s, early in done:
-            # the span also covers the release of the slot's accumulator
+            # the span also covers the release of the slot's counts
             with telemetry.span("repro.scheduler.finalize"):
                 self.finished.append(
                     self._finalize(self.active.pop(s), now, early=early))
@@ -965,10 +964,11 @@ class QueryScheduler:
         to fresh device buffers, so donation (the executable consumes its
         inputs) can never poison a retry.
 
-        Returns ``(counts, clean, dt)`` — ``counts`` the host ``[Q_b, n]``
-        tally, ``clean`` False for any wave that saw a fault, stall, retry,
-        or eviction (the EMA skips those). ``dt`` and the timeout cover the
-        dispatch, the wait for the device and the tally's copy to the host.
+        Returns ``(ends, clean, dt)`` — ``ends`` the host ``int32[W_b]``
+        endpoints (``-1`` for a discarded or dead walk), ``clean`` False for
+        any wave that saw a fault, stall, retry, or eviction (the EMA skips
+        those). ``dt`` and the timeout cover the dispatch, the wait for the
+        device and the endpoints' copy to the host.
         """
         wave_no = self._waves_run
         attempt = 0
@@ -1002,13 +1002,13 @@ class QueryScheduler:
                         jnp.asarray(self._lost))
                 with telemetry.span("repro.wave.device_wait"):
                     jax.block_until_ready(out)
-                counts = self._tally_to_host(out, Q_b)
+                ends = self._tally_to_host(out)
                 dt = time.perf_counter() - t0
                 if self.wave_timeout_s is not None and dt > self.wave_timeout_s:
                     raise WaveTimeout(
                         f"wave {wave_no} took {dt:.3g}s > wave_timeout_s="
                         f"{self.wave_timeout_s:.3g}s — result discarded")
-                return counts, clean, dt
+                return ends, clean, dt
             except ShardFault as e:
                 clean = False
                 if not e.transient:
@@ -1023,17 +1023,14 @@ class QueryScheduler:
                 clean = False
                 attempt = self._count_retry(wave_no, attempt, e)
 
-    def _tally_to_host(self, out, Q_b: int) -> np.ndarray:
-        """The wave's tally as a host ``int32[Q_b, n]``: one copy off the
-        device (the host loop's shards are on the host already), counted in
-        ``tally_bytes_out``, then shard-major ``[S, Q_b, sz]`` rows put back
-        in vertex order — the one place every dispatch path does this."""
+    def _tally_to_host(self, out) -> np.ndarray:
+        """The wave's endpoints as a host ``int32[W_b]``: the one copy off
+        the device for every dispatch path, counted in
+        ``tally_bytes_out``."""
         with telemetry.span("repro.wave.d2h"):
-            counts = np.asarray(out)
-            self._tally_bytes_out += counts.nbytes
-            if counts.ndim == 3:
-                counts = counts.transpose(1, 0, 2).reshape(Q_b, -1)
-            return counts[:, : self.g.n]
+            ends = np.asarray(out)
+            self._tally_bytes_out += ends.nbytes
+            return ends
 
     def _count_retry(self, wave_no: int, attempt: int,
                      err: Exception) -> int:
@@ -1221,9 +1218,8 @@ class QueryScheduler:
         # rank the integer counts (same order as the renormalized scores
         # — a positive scalar divide preserves ranks and ties exactly)
         # and divide only the selected head.
-        k = min(a.req.k, self.g.n)
-        top = _topk_stable(a.counts, k)
-        scores_top = a.counts[top] / float(max(1, a.executed))
+        top, hits = a.top(min(a.req.k, self.g.n))
+        scores_top = hits / float(max(1, a.executed))
         latency = now - a.t_submit
         # Early-stopped (anytime) queries carry the bound their executed
         # walks actually certify; budget-drained queries keep the plan's
@@ -1293,9 +1289,8 @@ class QueryScheduler:
                 continue
             k = min(a.req.k, self.g.n)
             if a.executed:
-                top = _topk_stable(a.counts, k)
-                vertices = top
-                top_scores = a.counts[top] / float(a.executed)
+                vertices, hits = a.top(k)
+                top_scores = hits / float(a.executed)
             else:
                 vertices = np.zeros(0, np.int64)
                 top_scores = np.zeros(0, np.float64)

@@ -665,7 +665,6 @@ class FrogWildService:
                 max_walks=scfg.max_walks, max_queries=scfg.max_queries,
                 max_steps=scfg.max_steps, p_T=self.config.p_T,
                 impl=self.config.kernel.stitch_impl,
-                tally_impl=self.config.kernel.tally_impl,
                 seed=self.config.runtime.seed, runtime=runtime,
                 wave_time_estimate_s=scfg.wave_time_estimate_s,
                 fault_injector=self._injector,

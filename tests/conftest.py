@@ -8,10 +8,10 @@ import warnings
 import pytest
 from hypothesis import settings
 
-# Donation of per-wave walk-state operands leaves the [Q+1, n] tally output
-# unable to alias the [W] donated inputs — expected, not a leak (see
-# repro/query/engine.py). pytest's warning capture overrides the library's
-# import-time filter, so repeat it here.
+# Donation of per-wave walk-state operands leaves some donated inputs with
+# no output to alias (one int32[W] output, five donated operands) —
+# expected, not a leak (see repro/query/engine.py). pytest's warning
+# capture overrides the library's import-time filter, so repeat it here.
 warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable")
 

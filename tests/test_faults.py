@@ -169,7 +169,8 @@ def test_transient_faults_retried_byte_identically_then_bounded():
         broke.step_wave()
     # the failed wave left nothing behind: no tallies, budget intact
     a = next(iter(broke.active.values()))
-    assert a.executed == 0 and a.remaining == 100 and a.counts.sum() == 0
+    assert a.executed == 0 and a.remaining == 100 and a.hits.sum() == 0
+    assert a.ids.size == 0
 
 
 def test_stall_detected_as_timeout_and_retried():

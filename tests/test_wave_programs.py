@@ -175,9 +175,9 @@ def test_wave_cache_shared_across_equal_geometry_schedulers():
 
 
 def test_topk_stable_matches_full_stable_argsort():
-    """Both the sparse (small positive support) and dense (partition)
-    strategies must reproduce the head of the full stable argsort exactly,
-    ties included."""
+    """The partition strategy (and the full sort when k ≥ n) must
+    reproduce the head of the full stable argsort exactly, ties and zeros
+    included."""
     from repro.query.scheduler import _topk_stable
     rng = np.random.default_rng(0)
     cases = [(1000, 10, 30), (1000, 10, 900), (1000, 25, 5),
@@ -190,7 +190,7 @@ def test_topk_stable_matches_full_stable_argsort():
         want = np.argsort(-counts, kind="stable")[:k]
         got = _topk_stable(counts, k)
         assert np.array_equal(got, want), (n, k, nnz)
-    # negative entries must route around the sparse path
+    # negative entries rank like any others
     scores = rng.normal(size=500)
     want = np.argsort(-scores, kind="stable")[:7]
     assert np.array_equal(_topk_stable(scores, 7), want)
